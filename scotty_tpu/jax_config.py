@@ -4,7 +4,10 @@ Import this module before tracing any engine-adjacent jitted function:
 * ``jax_enable_x64`` — event timestamps are int64 (epoch-ms exceeds int32);
   partial aggregates remain explicit float32.
 * persistent compilation cache — kernels are static per window/agg mix, so
-  repeat runs (tests, benchmarks) skip XLA compilation entirely.
+  repeat runs (tests, benchmarks) skip XLA compilation entirely. Where
+  ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing
+  here overrides it; otherwise the cache sits at one fixed path inside
+  the checkout (the path is part of the cache key, so it never moves).
 """
 
 import os
@@ -13,10 +16,9 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
-_cache_dir = os.environ.get("SCOTTY_TPU_COMPILE_CACHE",
-                            os.path.expanduser("~/.cache/scotty_tpu_xla"))
-try:
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-except Exception:                      # pragma: no cover - older jax
-    pass
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__))), ".jax_cache"))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)

@@ -42,10 +42,7 @@ from .routing import RoutingTable, plan_rebalance
 
 
 def _shard_map():
-    try:
-        from jax import shard_map          # jax >= 0.8
-    except ImportError:                    # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     return shard_map
 
 

@@ -226,8 +226,11 @@ class MeshKeyedPipeline(FusedPipelineDriver):
                         new_parts.append(jnp.maximum(acc, upd))
                 return tuple(new_parts), None
 
-            init = tuple(jnp.full((Kl, S, ag.width), ag.identity,
-                                  jnp.float32) for ag in aggs)
+            # the carry turns per-shard (varying over the key axis) in
+            # the body, so its replicated initial value is cast to match
+            init = jax.lax.pcast(
+                tuple(jnp.full((Kl, S, ag.width), ag.identity, jnp.float32)
+                      for ag in aggs), (a_name,), to="varying")
             parts, _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
 
             row_starts = base + g * jnp.arange(S, dtype=jnp.int64)
@@ -277,12 +280,10 @@ class MeshKeyedPipeline(FusedPipelineDriver):
         state_spec = {"buf": Pa, "keys": Pa}
         hit = _STEP_CACHE.get(cache_key)
         if hit is None:
-            # pallas_call has no shard_map replication rule yet: the
-            # flagged-on step disables the rep check (the out_specs
-            # above pin every output's sharding explicitly, so nothing
-            # is inferred from it); flags-off passes NOTHING extra —
-            # its call shape, trace and pin stay byte-identical
-            step_kw = {"check_rep": False} if pallas_fold else {}
+            # a pallas_call output carries no varying-axis type, so the
+            # flagged-on step turns the vma check off (out_specs pin
+            # every output's sharding explicitly); flags-off keeps it
+            step_kw = {"check_vma": False} if pallas_fold else {}
             hit = (
                 jax.jit(shard_map(
                     shard_body, mesh=mesh,

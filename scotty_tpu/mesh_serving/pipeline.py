@@ -284,8 +284,11 @@ class MeshServingPipeline(FusedPipelineDriver):
                         new_parts.append(jnp.maximum(acc, upd))
                 return tuple(new_parts), None
 
-            init = tuple(jnp.full((Kl, S, ag.width), ag.identity,
-                                  jnp.float32) for ag in aggs)
+            # the carry turns per-shard (varying over the key axis) in
+            # the body, so its replicated initial value is cast to match
+            init = jax.lax.pcast(
+                tuple(jnp.full((Kl, S, ag.width), ag.identity, jnp.float32)
+                      for ag in aggs), (a_name,), to="varying")
             parts, _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
 
             row_starts = base + g * jnp.arange(S, dtype=jnp.int64)

@@ -20,7 +20,7 @@ reduce each block into a VMEM row accumulator — no scatter anywhere:
   of scattering per lane.
 * :func:`build_segment_fold` — variable segments bounded by ``runs``
   (the ``build_ingest_dense`` contract: an in-order batch touches a
-  contiguous run range): sorted run ids, one [runs, width] accumulator.
+  contiguous run range): sorted run ids, one [width, runs] accumulator.
 
 ``packed=True`` streams the lifted values as bf16 — half the HBM
 traffic per lane; the accumulator stays f32, so the only precision loss
@@ -36,15 +36,23 @@ speed claims stay TPU-box certifications.
 
 from __future__ import annotations
 
+import numpy as np
+
+#: block index for a whole axis: int32, because under x64 a bare ``0``
+#: becomes an int64 that Mosaic's index maps cannot return
+_I0 = np.int32(0)
+
 
 def _chunk(lanes: int, cap: int = 512) -> int:
-    """Largest divisor of ``lanes`` at most ``cap`` — the lane-block
-    size (the streaming granularity)."""
+    """The lane-block size (the streaming granularity): the largest
+    divisor of ``lanes`` at most ``cap`` that is a multiple of 128 (the
+    vreg lane width), else all of ``lanes`` — a block dimension must be
+    tile-aligned or span the whole axis."""
     lanes, cap = int(lanes), int(cap)
-    b = min(lanes, cap)
-    while lanes % b:
-        b -= 1
-    return max(b, 1)
+    for b in range(min(lanes, cap) // 128 * 128, 0, -128):
+        if lanes % b == 0:
+            return b
+    return lanes
 
 
 def _reducer(kind: str):
@@ -57,6 +65,14 @@ def _reducer(kind: str):
     if kind == "max":
         return jnp.max, jnp.maximum
     raise ValueError(f"unknown combine kind {kind!r}")
+
+
+# Layout: every streamed operand keeps its lanes on the minor (128-wide)
+# axis and the small aggregate width / cell count on the axis above it.
+# The natural ``[lanes, width]`` layout would pad a width-1 operand to
+# 128 lanes in HBM (a 128x blow-up); here each block reduces along its
+# lanes into a ``(width, 1)`` column, and outputs are ``[rows, width,
+# 1]`` so a block spans both tiled axes whole.
 
 
 def row_fold(lifted, rows: int, lanes: int, kind: str,
@@ -85,21 +101,20 @@ def row_fold(lifted, rows: int, lanes: int, kind: str,
 
         @pl.when(c == 0)
         def _init():
-            o_ref[...] = jnp.full((1, W), ident, jnp.float32)
+            o_ref[...] = jnp.full((W, 1), ident, jnp.float32)
 
-        vb = v_ref[...].astype(jnp.float32)          # [lb, W]
-        o_ref[...] = comb(o_ref[...], red(vb, axis=0, keepdims=True))
+        vb = v_ref[...].astype(jnp.float32)          # [W, lb]
+        o_ref[...] = comb(o_ref[...], red(vb, axis=1, keepdims=True))
 
     out = pl.pallas_call(
         kernel,
         grid=(rows, chunks),
-        in_specs=[pl.BlockSpec((lb, W),
-                               lambda r, c: (r * chunks + c, 0))],
-        out_specs=pl.BlockSpec((1, W), lambda r, c: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, W), jnp.float32),
+        in_specs=[pl.BlockSpec((None, W, lb), lambda r, c: (r, _I0, c))],
+        out_specs=pl.BlockSpec((None, W, 1), lambda r, c: (r, _I0, _I0)),
+        out_shape=jax.ShapeDtypeStruct((rows, W, 1), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(lifted.reshape(rows * lanes, W))
-    return out
+    )(lifted.reshape(rows, lanes, W).transpose(0, 2, 1))
+    return out.reshape(rows, W)
 
 
 def sparse_row_fold(col, val, rows: int, lanes: int, width: int,
@@ -121,7 +136,7 @@ def sparse_row_fold(col, val, rows: int, lanes: int, width: int,
         col = col[None, :]
         val = val[None, :]
     cells = int(col.shape[0])
-    lb = _chunk(lanes, cap=max(1, (1 << 16) // max(width, 1)))
+    lb = _chunk(lanes, cap=max(128, (1 << 16) // max(width, 1)))
     chunks = lanes // lb
     red, comb = _reducer(kind)
     ident = float(identity)
@@ -131,32 +146,31 @@ def sparse_row_fold(col, val, rows: int, lanes: int, width: int,
 
         @pl.when(c == 0)
         def _init():
-            o_ref[...] = jnp.full((1, width), ident, jnp.float32)
+            o_ref[...] = jnp.full((width, 1), ident, jnp.float32)
 
         acc = o_ref[...]
-        wcols = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        wrows = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
         for d in range(cells):                       # static cell loop
-            cb = c_ref[d, :].astype(jnp.int32)       # [lb]
-            vb = v_ref[d, :].astype(jnp.float32)
-            hit = cb[:, None] == wcols               # [lb, width]
-            dense = jnp.where(hit, vb[:, None], ident)
-            acc = comb(acc, red(dense, axis=0, keepdims=True))
+            cb = c_ref[d:d + 1, :]                   # [1, lb]
+            vb = v_ref[d:d + 1, :]
+            dense = jnp.where(cb == wrows, vb, ident)   # [width, lb]
+            acc = comb(acc, red(dense, axis=1, keepdims=True))
         o_ref[...] = acc
 
+    def by_row(x, dtype):
+        return x.astype(dtype).reshape(cells, rows, lanes).transpose(1, 0, 2)
+
+    blk = pl.BlockSpec((None, cells, lb), lambda r, c: (r, _I0, c))
     out = pl.pallas_call(
         kernel,
         grid=(rows, chunks),
-        in_specs=[
-            pl.BlockSpec((cells, lb),
-                         lambda r, c: (0, r * chunks + c)),
-            pl.BlockSpec((cells, lb),
-                         lambda r, c: (0, r * chunks + c)),
-        ],
-        out_specs=pl.BlockSpec((1, width), lambda r, c: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, width), jnp.float32),
+        in_specs=[blk, blk],
+        out_specs=pl.BlockSpec((None, width, 1),
+                               lambda r, c: (r, _I0, _I0)),
+        out_shape=jax.ShapeDtypeStruct((rows, width, 1), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(col.astype(jnp.int32), val.astype(jnp.float32))
-    return out
+    )(by_row(col, jnp.int32), by_row(val, jnp.float32))
+    return out.reshape(rows, width)
 
 
 def build_segment_fold(batch: int, runs: int, width: int, kind: str,
@@ -167,7 +181,7 @@ def build_segment_fold(batch: int, runs: int, width: int, kind: str,
 
     Invalid lanes carry identity-masked values (the caller's existing
     ``_lift`` mask), so any run id they alias combines a no-op. One
-    [runs, width] VMEM accumulator lives across the lane-chunk grid;
+    [width, runs] VMEM accumulator lives across the lane-chunk grid;
     the tiny [runs]-lane buffer update stays with the caller.
     """
     import jax
@@ -187,33 +201,31 @@ def build_segment_fold(batch: int, runs: int, width: int, kind: str,
 
         @pl.when(c == 0)
         def _init():
-            o_ref[...] = jnp.full((R, W), ident, jnp.float32)
+            o_ref[...] = jnp.full((W, R), ident, jnp.float32)
 
-        kb = k_ref[...]                              # [lb]
-        vb = v_ref[...].astype(jnp.float32)          # [lb, W]
-        acc = o_ref[...]
-        upds = []
+        kb = k_ref[...]                              # [1, lb]
+        vb = v_ref[...].astype(jnp.float32)          # [W, lb]
         for r in range(R):                           # static runs loop
-            sel = (kb == r)[:, None]
-            upds.append(red(jnp.where(sel, vb, ident), axis=0,
-                            keepdims=True))
-        o_ref[...] = comb(acc, jnp.concatenate(upds, axis=0))
+            upd = red(jnp.where(kb == r, vb, ident), axis=1,
+                      keepdims=True)                 # [W, 1]
+            o_ref[:, r:r + 1] = comb(o_ref[:, r:r + 1], upd)
 
     def fold(k, lifted):
         lifted = jnp.asarray(lifted)
         if packed:
             lifted = lifted.astype(jnp.bfloat16)
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kernel,
             grid=(chunks,),
             in_specs=[
-                pl.BlockSpec((lb,), lambda c: (c,)),
-                pl.BlockSpec((lb, W), lambda c: (c, 0)),
+                pl.BlockSpec((1, lb), lambda c: (_I0, c)),
+                pl.BlockSpec((W, lb), lambda c: (_I0, c)),
             ],
-            out_specs=pl.BlockSpec((R, W), lambda c: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((R, W), jnp.float32),
+            out_specs=pl.BlockSpec((W, R), lambda c: (_I0, _I0)),
+            out_shape=jax.ShapeDtypeStruct((W, R), jnp.float32),
             interpret=resolve_interpret(interpret),
-        )(jnp.asarray(k, jnp.int32), lifted)
+        )(jnp.asarray(k, jnp.int32).reshape(1, B), lifted.T)
+        return out.T
 
     return fold
 

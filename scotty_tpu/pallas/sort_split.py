@@ -18,7 +18,8 @@ batch span does. The kernel then:
   pairs entirely in VMEM — the lane id breaks ties, which makes the
   network order IDENTICAL to the XLA twin's stable sort (equal
   timestamps keep arrival order), and the compare-exchange partners
-  come from pure reshape/flip moves (no gathers on the hot loop),
+  come from lane/row rotations of a ``[rows, 128]`` layout (no gathers
+  on the hot loop),
 * emits the permutation and the sorted bucket keys; the wrapper
   reconstructs the sorted int64 timestamps from ``ts_min`` + bucket and
   splits against the operator's max-event-time mirror (``cut``) with
@@ -48,28 +49,51 @@ def sort_span_fits(span: int) -> bool:
     return 0 <= int(span) < int(_INVALID_KEY) - 1
 
 
+def _layout(B: int):
+    """The kernel's 2-D ``[rows, lanes]`` view of a ``[B]`` batch: full
+    128-lane rows (the vreg width), fewer lanes only for a tiny batch."""
+    lanes = min(B, 128)
+    return B // lanes, lanes
+
+
 def _bitonic_argsort_kernel(B: int):
     """Kernel body: ascending bitonic network over (key, lane) pairs.
 
-    ``B`` is a static power of two. Partners at stride j are pure
-    reshape/flip moves ([B] -> [B/2j, 2, j] -> flip axis 1), keys and
-    lane ids stay int32 in VMEM for the whole network.
+    ``B`` is a static power of two, laid out ``[rows, lanes]``. The
+    partner of position ``p`` at stride ``j`` is ``p ^ j``: a rotation
+    by ``j`` along the lane axis (``j < lanes``) or by ``j / lanes``
+    along the row axis. Rotating the position iota with the same roll
+    picks, per element, which of the two opposite rotations reached the
+    partner, so the network does not depend on the roll's direction
+    convention. Keys and lane ids stay int32 in VMEM throughout.
     """
     import jax
     import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
 
-    def swap(a, j):
-        return jnp.flip(a.reshape(B // (2 * j), 2, j), axis=1).reshape(B)
+    rows, lanes = _layout(B)
 
     def kernel(k_ref, perm_ref, sk_ref):
         k = k_ref[...]
-        idx = jax.lax.broadcasted_iota(jnp.int32, (B,), 0)
-        ids = idx
+        ids = (jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
+               * lanes
+               + jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1))
+        idx = ids
         size = 2
         while size <= B:
             j = size // 2
             while j >= 1:
-                pk, pi = swap(k, j), swap(idx, j)
+                axis, step = (1, j) if j < lanes else (0, j // lanes)
+                # int32 shifts: Mosaic's rotate takes no int64 operand
+                back = np.int32((rows, lanes)[axis] - step)
+                step = np.int32(step)
+                fwd = pltpu.roll(ids, step, axis) == (ids ^ j)
+
+                def partner(a):
+                    return jnp.where(fwd, pltpu.roll(a, step, axis),
+                                     pltpu.roll(a, back, axis))
+
+                pk, pi = partner(k), partner(idx)
                 want_min = ((ids & j) == 0) == ((ids & size) == 0)
                 # (key, lane) pairs are unique, so "mine > partner" is
                 # a total order — no equality arm needed
@@ -91,14 +115,16 @@ def _argsort_call(B: int, interpret):
     from jax.experimental import pallas as pl
 
     kernel = _bitonic_argsort_kernel(B)
+    shape = _layout(B)
 
     def argsort(k32):
-        return pl.pallas_call(
+        perm, sk = pl.pallas_call(
             kernel,
-            out_shape=(jax.ShapeDtypeStruct((B,), jnp.int32),
-                       jax.ShapeDtypeStruct((B,), jnp.int32)),
+            out_shape=(jax.ShapeDtypeStruct(shape, jnp.int32),
+                       jax.ShapeDtypeStruct(shape, jnp.int32)),
             interpret=resolve_interpret(interpret),
-        )(k32)
+        )(k32.reshape(shape))
+        return perm.reshape(B), sk.reshape(B)
 
     return argsort
 

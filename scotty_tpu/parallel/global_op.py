@@ -67,10 +67,7 @@ class GlobalTpuWindowOperator(KeyedTpuWindowOperator):
             return jax.jit(local_block)
 
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map          # current home (jax >= 0.8)
-        except ImportError:                    # pragma: no cover
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         coll = {"sum": jax.lax.psum, "min": jax.lax.pmin,
                 "max": jax.lax.pmax}

@@ -31,7 +31,7 @@ at the overflow drain points. This module makes that a *policy*:
 
 All policy work is gated host-side on ``config.overflow_policy``; under
 ``FAIL`` the jitted steps and the per-batch host path are byte-identical
-to the seed (the bench A/B bound in BASELINE.md).
+to the seed (the bench A/B bound).
 """
 
 from __future__ import annotations

@@ -352,3 +352,31 @@ def test_sketch_lower_device_matches_host():
         ok = np.isclose(want, got, rtol=1e-3) | (np.isnan(want)
                                                  & np.isnan(got))
         assert ok.all(), (spec.token, want, got)
+
+
+# ---------------------------------------------------------------------------
+# compile cache placement (scotty_tpu/jax_config.py)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("env_dir", [None, "/nonexistent/jax-cache"])
+def test_compile_cache_dir_follows_env_else_checkout(env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the cache sits
+    at one fixed path inside the checkout. A fresh interpreter, because
+    the choice is made when the module is first imported."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run(
+        [sys.executable, "-c", "import jax, scotty_tpu.jax_config; "
+         "print(jax.config.jax_compilation_cache_dir)"],
+        cwd=repo, env=env, capture_output=True, text=True, timeout=120,
+        check=True).stdout.strip()
+    assert out == (env_dir or os.path.join(repo, ".jax_cache"))

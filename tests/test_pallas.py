@@ -55,14 +55,17 @@ def _leaves_equal(a, b):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("B", [128, 1024])
 @pytest.mark.parametrize("seed", [0, 1, 2, 7])
-def test_sort_split_differential_chaos(seed):
+def test_sort_split_differential_chaos(seed, B):
+    """B=128 is one 128-lane row; B=1024 spans eight, so strides of a
+    row or more take the cross-row partner path."""
     import jax
 
     from scotty_tpu import pallas as spl
 
     rng = np.random.default_rng(seed)
-    B, L = 128, 32
+    L = {128: 32, 1024: 512}[B]           # room for every late lane
     lo = int(rng.integers(0, 1000))
     span = int(rng.integers(10, 4000))
     ts = rng.integers(lo, lo + span, size=B).astype(np.int64)

@@ -448,10 +448,14 @@ def test_load_fingerprint_from_recorded_cell():
 
 
 def test_obs_trend_reconstructs_rounds_and_exit_codes(tmp_path):
-    paths = sorted(
-        os.path.join(REPO, f) for f in os.listdir(REPO)
-        if f.startswith("BENCH_r") and f.endswith(".json"))
-    assert len(paths) >= 5
+    # five round records in the driver's shape, each faster than the last
+    paths = [_write(tmp_path / f"BENCH_r{n:02d}.json",
+                    {"n": n, "cmd": "python bench.py", "rc": 0, "tail": "",
+                     "parsed": {"metric": "tuples_per_sec",
+                                "value": 1e9 * n,
+                                "p99_window_emit_ms": 200.0 - 10 * n,
+                                "rtt_floor_ms": 50.0}})
+             for n in range(1, 6)]
     trend = build_trend(paths=paths, results_dir=RESULTS)
     assert [r["round"] for r in trend["rounds"]] \
         == sorted(r["round"] for r in trend["rounds"])
