@@ -12,16 +12,18 @@ Default (one chip), in order, stopping at the first failure:
     windows must equal the ``SlicingWindowOperator`` simulator's.
 (b) fused headline — ``AlignedStreamPipeline`` at ``bench.py``'s config
     (Sliding(60000,1): 60,000 concurrent windows) with the offered load
-    pinned at ``OFFERED_SWEEP[0]``. Interval 0's windows are recomputed
-    on the host from the replayed stream; the pre-roll then fills the
-    window span and the following intervals must emit.
+    pinned at ``OFFERED_SWEEP[0]``. The first windows close in
+    intervals 59 and 60; theirs are recomputed on the host from the
+    replayed stream of intervals 0-60, and the intervals after the
+    pre-roll must emit.
 (c) keyed — ``KeyedAlignedPipeline`` at ``keyed_throughput.json`` (1024
     keys, capacity 128); two keys' windows are recomputed on the host
     from their replayed streams.
 
 ``--chips 4`` runs instead (d): the mesh keyed cell at ``mesh_keyed.json``
 (65,536 keys over a 4-chip mesh, rebalance on) with its host-oracle and
-mid-run-rebalance arms, a check that every chip holds one shard, and
+mid-run-rebalance arms, a check that the cell's pipeline holds one shard
+on every chip, and
 ``GlobalTpuWindowOperator``'s cross-shard combine against the host total.
 
 One process drives the chip. Each phase prints one JSON line; the last
@@ -275,7 +277,6 @@ def mesh(n_chips=4, cfg=None):
     from scotty_tpu import SumAggregation
     from scotty_tpu.bench.runner import run_mesh_keyed_cell
     from scotty_tpu.engine import EngineConfig
-    from scotty_tpu.mesh import MeshKeyedPipeline
     from scotty_tpu.parallel import GlobalTpuWindowOperator
 
     devs = jax.devices()[:n_chips]
@@ -288,23 +289,11 @@ def mesh(n_chips=4, cfg=None):
         raise AssertionError(f"mesh cell: oracle_match={r.oracle_match} "
                              f"rebalance_match={r.rebalance_match}")
 
-    # built as the cell builds its pipelines, so the step cache hits
-    p = MeshKeyedPipeline(_windows(), [SumAggregation()],
-                          n_keys=cfg.n_keys, n_shards=n_chips,
-                          config=EngineConfig(capacity=cfg.capacity,
-                                              annex_capacity=8,
-                                              min_trigger_pad=32),
-                          throughput=cfg.throughput,
-                          wm_period_ms=cfg.watermark_period_ms,
-                          max_lateness=cfg.max_lateness, seed=cfg.seed)
-    p.reset()
-    p.run(1)
-    shards = p.state["buf"].partials[0].addressable_shards
-    holders = sorted(s.device.id for s in shards)
-    if holders != sorted(d.id for d in devs) or \
-            any(s.data.shape[0] != cfg.n_keys // n_chips for s in shards):
+    placement = [tuple(x) for x in r.shard_placement]
+    if placement != [(i, cfg.n_keys // n_chips)
+                     for i in sorted(d.id for d in devs)]:
         raise AssertionError(f"mesh state not one shard per chip: "
-                             f"{[(s.device.id, s.data.shape) for s in shards]}")
+                             f"{placement}")
 
     rng = np.random.default_rng(0)
     gop = GlobalTpuWindowOperator(
@@ -324,7 +313,7 @@ def mesh(n_chips=4, cfg=None):
     if total != want:
         raise AssertionError(f"global combine {total} != host {want}")
     return {"n_keys": r.n_keys, "n_shards": r.n_shards,
-            "shard_devices": holders,
+            "shard_placement": r.shard_placement,
             "windows_emitted": r.n_windows_emitted,
             "oracle_match": r.oracle_match,
             "rebalance_match": r.rebalance_match,
@@ -355,10 +344,9 @@ def main(argv=None):
         _log(f"{name}: start")
         info = run()
         print(json.dumps({"phase": name, "ok": True, **info}), flush=True)
-    d = jax.devices()[0]
     print(json.dumps({"ok": True, "device": {
-        "platform": d.platform, "kind": d.device_kind,
-        "count": len(jax.devices())}}))
+        "platform": devs[0].platform, "kind": devs[0].device_kind,
+        "count": args.chips}}))
     return 0
 
 

@@ -7,7 +7,8 @@ Import this module before tracing any engine-adjacent jitted function:
   repeat runs (tests, benchmarks) skip XLA compilation entirely. Where
   ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing
   here overrides it; otherwise the cache sits at one fixed path inside
-  the checkout (the path is part of the cache key, so it never moves).
+  the checkout, so a second run, or a copy of the checkout, finds what
+  an earlier run compiled.
 """
 
 import os

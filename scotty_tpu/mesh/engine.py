@@ -46,6 +46,28 @@ def _shard_map():
     return shard_map
 
 
+def halving_sum(x, axis: int):
+    """Sum ``x`` over ``axis`` by repeated halving: a pairwise tree whose
+    grouping depends only on that axis's length. XLA's own reduce splits
+    by the whole operand's shape (on CPU, a ``[2, S, 200]`` shard and a
+    ``[16, S, 200]`` shard group the 200 adds differently), so a shard's
+    per-slice sums would change bits with its key count; this keeps the
+    mesh steps bit-identical under any shard count."""
+    import jax
+    import jax.numpy as jnp
+
+    n = x.shape[axis]
+    while n > 1:
+        h = n // 2
+        y = jax.lax.slice_in_dim(x, 0, h, axis=axis) \
+            + jax.lax.slice_in_dim(x, h, 2 * h, axis=axis)
+        if n % 2:
+            y = jnp.concatenate(
+                [y, jax.lax.slice_in_dim(x, 2 * h, n, axis=axis)], axis)
+        x, n = y, h + n % 2
+    return jnp.squeeze(x, axis)
+
+
 def _mesh_token(mesh, axis: str) -> tuple:
     """Hashable identity of a mesh for kernel-cache keys: the device ids
     + axis name (two make_mesh calls over the same devices ARE the same

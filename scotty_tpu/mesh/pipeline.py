@@ -38,7 +38,7 @@ from ..core.windows import SlidingWindow, TumblingWindow, WindowMeasure
 from ..engine.config import EngineConfig
 from ..engine.pipeline import FusedPipelineDriver
 from .routing import RoutingTable
-from .engine import _mesh_token, _shard_map
+from .engine import _mesh_token, _shard_map, halving_sum
 
 #: jitted (step, gc) per (windows, aggs, shapes, mesh) — bench cells and
 #: test suites build several pipeline twins without recompiling
@@ -217,7 +217,11 @@ class MeshKeyedPipeline(FusedPipelineDriver):
                     else:
                         lifted = aspec.lift_dense(flat) \
                             .reshape(Kl, S, Rc, -1)
-                        upd = red[aspec.kind](lifted, axis=2)
+                        # sums fold in a fixed order so the bits
+                        # do not depend on the shard's key count
+                        upd = halving_sum(lifted, 2) \
+                            if aspec.kind == "sum" \
+                            else red[aspec.kind](lifted, axis=2)
                     if aspec.kind == "sum":
                         new_parts.append(acc + upd)
                     elif aspec.kind == "min":

@@ -52,7 +52,8 @@ from ..engine.pipeline import (
     SlotGeometry,
     build_slot_trigger_grid,
 )
-from ..mesh.engine import _mesh_token, _shard_map, make_row_permuter
+from ..mesh.engine import (_mesh_token, _shard_map, halving_sum,
+                           make_row_permuter)
 from ..mesh.routing import RoutingTable
 
 #: jitted (step, gc) per (geometry, aggs, shapes, mesh, trace-cell id)
@@ -275,7 +276,11 @@ class MeshServingPipeline(FusedPipelineDriver):
                     else:
                         lifted = aspec.lift_dense(flat) \
                             .reshape(Kl, S, Rc, -1)
-                        upd = red[aspec.kind](lifted, axis=2)
+                        # sums fold in a fixed order so the bits
+                        # do not depend on the shard's key count
+                        upd = halving_sum(lifted, 2) \
+                            if aspec.kind == "sum" \
+                            else red[aspec.kind](lifted, axis=2)
                     if aspec.kind == "sum":
                         new_parts.append(acc + upd)
                     elif aspec.kind == "min":

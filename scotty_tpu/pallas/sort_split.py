@@ -62,10 +62,12 @@ def _bitonic_argsort_kernel(B: int):
     ``B`` is a static power of two, laid out ``[rows, lanes]``. The
     partner of position ``p`` at stride ``j`` is ``p ^ j``: a rotation
     by ``j`` along the lane axis (``j < lanes``) or by ``j / lanes``
-    along the row axis. Rotating the position iota with the same roll
-    picks, per element, which of the two opposite rotations reached the
-    partner, so the network does not depend on the roll's direction
-    convention. Keys and lane ids stay int32 in VMEM throughout.
+    along the row axis. ``pltpu.roll`` rotates like ``jnp.roll``
+    (``out[p] = in[p - shift]``), so a position with bit ``j`` clear
+    reads its partner ``p + j`` from the backward rotation and one with
+    it set reads ``p - j`` from the forward one (tests/test_pallas.py
+    checks the direction). Keys and lane ids stay int32 in VMEM
+    throughout.
     """
     import jax
     import jax.numpy as jnp
@@ -87,14 +89,14 @@ def _bitonic_argsort_kernel(B: int):
                 # int32 shifts: Mosaic's rotate takes no int64 operand
                 back = np.int32((rows, lanes)[axis] - step)
                 step = np.int32(step)
-                fwd = pltpu.roll(ids, step, axis) == (ids ^ j)
+                low = (ids & j) == 0
 
                 def partner(a):
-                    return jnp.where(fwd, pltpu.roll(a, step, axis),
-                                     pltpu.roll(a, back, axis))
+                    return jnp.where(low, pltpu.roll(a, back, axis),
+                                     pltpu.roll(a, step, axis))
 
                 pk, pi = partner(k), partner(idx)
-                want_min = ((ids & j) == 0) == ((ids & size) == 0)
+                want_min = low == ((ids & size) == 0)
                 # (key, lane) pairs are unique, so "mine > partner" is
                 # a total order — no equality arm needed
                 mine_gt = (k > pk) | ((k == pk) & (idx > pi))

@@ -373,15 +373,11 @@ def test_supervisor_checkpoint_and_rebalance_with_lineage_fallback(
 
 
 def _make_pipeline(n_shards, seed=13, n_keys=16):
-    # power-of-two value scale: every window sum is exact in f32, so the
-    # shard-count comparisons below can demand bit equality whatever
-    # order the backend reduces a shard's rows in
     windows = [TumblingWindow(Time, 100), SlidingWindow(Time, 500, 100)]
     p = MeshKeyedPipeline(
         windows, [SumAggregation(), MaxAggregation()], n_keys=n_keys,
         n_shards=n_shards, config=CFG, throughput=n_keys * 2000,
-        wm_period_ms=100, max_lateness=100, seed=seed, gc_every=3,
-        value_scale=8.0)
+        wm_period_ms=100, max_lateness=100, seed=seed, gc_every=3)
     p.reset()
     return p
 

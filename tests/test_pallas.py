@@ -101,6 +101,26 @@ def test_sort_split_differential_chaos(seed, B):
     np.testing.assert_array_equal(l_vals[:n_late], sort_vals[:n_late])
 
 
+@pytest.mark.parametrize("axis", [0, 1])
+def test_pallas_roll_rotates_like_jnp_roll(axis):
+    """The bitonic network takes ``out[p] = in[p - shift]`` as
+    ``pltpu.roll``'s direction when it picks a partner's rotation."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    x = np.arange(8 * 128, dtype=np.int32).reshape(8, 128)
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = pltpu.roll(x_ref[...], np.int32(3), axis)
+
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(x.shape, jnp.int32),
+        interpret=True)(x)
+    np.testing.assert_array_equal(np.asarray(got), np.roll(x, 3, axis))
+
+
 def test_sort_split_rejects_non_power_of_two():
     from scotty_tpu import pallas as spl
 
