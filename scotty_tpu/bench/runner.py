@@ -109,7 +109,6 @@ CELL_EXTRA_FIELDS = (
     "ring_occupancy_p99",
     "host_staged_p50", "host_staged_p90",
     "host_staged_p99",
-    "prefetch_overlap_ratio",
     "ring_full_events", "ring_shed",
     "ring_blocks", "baseline_per_record_tps",
     "speedup_vs_per_record", "platform",
@@ -1335,7 +1334,6 @@ def run_ring_fed_cell(cfg: BenchmarkConfig, window_spec: str,
         n_windows_emitted=emitted, n_tuples=n_tuples, wall_s=wall)
     res.emit_ms_device = wall / max(1, len(pending)) * 1e3
     snap = feed.snapshot()
-    res.prefetch_overlap_ratio = feed.feeder.overlap_ratio()
     res.ring_full_events = int(snap["full_events"])
     res.ring_shed = int(snap["shed"])
     res.ring_blocks = int(snap["blocks"])
@@ -2093,7 +2091,6 @@ def run_ingest_external_cell(cfg: BenchmarkConfig, window_spec: str,
     res.host_staged_p50 = float(np.percentile(occ[:, 1], 50))
     res.host_staged_p90 = float(np.percentile(occ[:, 1], 90))
     res.host_staged_p99 = float(np.percentile(occ[:, 1], 99))
-    res.prefetch_overlap_ratio = feed.feeder.overlap_ratio()
     snap = feed.snapshot()
     res.ring_full_events = int(snap["full_events"])
     res.ring_shed = int(snap["shed"])

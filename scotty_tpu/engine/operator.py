@@ -1633,7 +1633,9 @@ class TpuWindowOperator(WindowOperator):
         else:
             # dense scatter-free variant when the span bound allows
             kern = self._pick_inorder_kernel(ts_min, ts_max)
-        self._state = kern(self._state, ts, vals, valid)
+        with _obs.program_span(self.obs, "ingest.dispatch", lanes=B,
+                               n_valid=n, late=has_late):
+            self._state = kern(self._state, ts, vals, valid)
         if self._has_count:
             # device batches with count windows are in-order by contract
             self._rec = self._rec_append(self._rec, ts, vals, valid)
@@ -1667,7 +1669,9 @@ class TpuWindowOperator(WindowOperator):
         self._host_min_ts = ts_min if self._host_min_ts is None \
             else min(self._host_min_ts, ts_min)
         self._host_count += n
-        self._state = self._ingest(self._state, ts, vals, valid)
+        with _obs.program_span(self.obs, "ingest.dispatch",
+                               lanes=ts.shape[0], n_valid=n, late=True):
+            self._state = self._ingest(self._state, ts, vals, valid)
 
     # -- watermark ---------------------------------------------------------
     def process_watermark(self, watermark_ts: int) -> List[AggregateWindow]:
@@ -1748,17 +1752,20 @@ class TpuWindowOperator(WindowOperator):
     def _process_watermark_dispatch(self, watermark_ts: int):
         if not self._built:
             self._build()
-        if self._shaper is not None:
-            # event time is about to advance past anything still held in
-            # the shaper's accumulator — drain it first (the shaper's
-            # bounded-delay contract also caps how much can be here)
-            self._shaper.flush()
-        if self._ingest_feed is not None:
-            # same contract for the ingest ring: records still staged
-            # (accumulator slack band, partial block, prefetch stage)
-            # must land before the watermark sweeps past them
-            self._ingest_feed.drain()
-        self._flush()
+        span = _obs.program_span
+        with span(self.obs, "watermark.flush_ingest", wm=watermark_ts):
+            if self._shaper is not None:
+                # event time is about to advance past anything still held
+                # in the shaper's accumulator — drain it first (the
+                # shaper's bounded-delay contract also caps how much can
+                # be here)
+                self._shaper.flush()
+            if self._ingest_feed is not None:
+                # same contract for the ingest ring: records still staged
+                # (accumulator slack band, partial block, prefetch stage)
+                # must land before the watermark sweeps past them
+                self._ingest_feed.drain()
+            self._flush()
         if self._pure_session:
             outs = self._sweep_sessions(watermark_ts)
             self._last_watermark = watermark_ts
@@ -1793,7 +1800,8 @@ class TpuWindowOperator(WindowOperator):
             last_wm = max(last_wm, self._host_first_ts)
 
         if self._annex_dirty:
-            self._state = self._merge(self._state)
+            with span(self.obs, "watermark.merge", wm=watermark_ts):
+                self._state = self._merge(self._state)
             st = self._state
             self._annex_dirty = False
 
@@ -1807,76 +1815,85 @@ class TpuWindowOperator(WindowOperator):
                        if self._count_late_seen
                        else self._count_at(st, np.int64(watermark_ts)))
 
-        trig_s, trig_e, trig_c = [], [], []
-        for w, act in zip(self.windows, self._win_active):
-            if not act:
-                continue              # cancelled query: mask, not rebuild
-            if isinstance(w, (SessionWindow, ForwardContextAware,
-                              ForwardContextFree)):
-                continue              # context windows emit via their sweeps
-            if w.measure == WindowMeasure.Count:
-                s_arr, e_arr = w.trigger_arrays(self._last_count, cend + 1)
-                trig_c.append(np.ones(s_arr.shape[0], bool))
-            else:
-                s_arr, e_arr = w.trigger_arrays(last_wm, watermark_ts)
-                trig_c.append(np.zeros(s_arr.shape[0], bool))
-            trig_s.append(s_arr)
-            trig_e.append(e_arr)
-        ws = np.concatenate(trig_s) if trig_s else empty
-        we = np.concatenate(trig_e) if trig_e else empty
-        is_count = (np.concatenate(trig_c) if trig_c
-                    else np.empty(0, dtype=bool))
-        T = ws.shape[0]
-        if T > self.config.max_triggers:
-            raise RuntimeError(
-                f"{T} triggered windows exceeds max_triggers="
-                f"{self.config.max_triggers}")
+        with span(self.obs, "watermark.trigger", wm=watermark_ts) as ann:
+            trig_s, trig_e, trig_c = [], [], []
+            for w, act in zip(self.windows, self._win_active):
+                if not act:
+                    continue          # cancelled query: mask, not rebuild
+                if isinstance(w, (SessionWindow, ForwardContextAware,
+                                  ForwardContextFree)):
+                    continue          # context windows emit via sweeps
+                if w.measure == WindowMeasure.Count:
+                    s_arr, e_arr = w.trigger_arrays(self._last_count,
+                                                    cend + 1)
+                    trig_c.append(np.ones(s_arr.shape[0], bool))
+                else:
+                    s_arr, e_arr = w.trigger_arrays(last_wm, watermark_ts)
+                    trig_c.append(np.zeros(s_arr.shape[0], bool))
+                trig_s.append(s_arr)
+                trig_e.append(e_arr)
+            ws = np.concatenate(trig_s) if trig_s else empty
+            we = np.concatenate(trig_e) if trig_e else empty
+            is_count = (np.concatenate(trig_c) if trig_c
+                        else np.empty(0, dtype=bool))
+            T = ws.shape[0]
+            ann.set_metadata(T=T)
+            if T > self.config.max_triggers:
+                raise RuntimeError(
+                    f"{T} triggered windows exceeds max_triggers="
+                    f"{self.config.max_triggers}")
+            if T:
+                Tp = self.config.trigger_pad(T)
+                ws_p = np.zeros((Tp,), np.int64)
+                we_p = np.zeros((Tp,), np.int64)
+                mask = np.zeros((Tp,), bool)
+                ic_p = np.zeros((Tp,), bool)
+                ws_p[:T], we_p[:T], mask[:T] = ws, we, True
+                ic_p[:T] = is_count
 
         cnt_d = results = None
         if T:
-            Tp = self.config.trigger_pad(T)
-            ws_p = np.zeros((Tp,), np.int64)
-            we_p = np.zeros((Tp,), np.int64)
-            mask = np.zeros((Tp,), bool)
-            ic_p = np.zeros((Tp,), bool)
-            ws_p[:T], we_p[:T], mask[:T] = ws, we, True
-            ic_p[:T] = is_count
-            if self._has_count and self._count_late_seen:
-                if self._grid_spec.has_time_grid:
-                    # the reference final-merge's batch scan bounds
-                    # (WindowManager.java:98-118 → LazyAggregateStore
-                    # .aggregate): defaults LONG_MAX/0, count default =
-                    # current count; duplicates shadow (see build_query)
-                    tm = ~is_count
-                    min_ts = int(ws[tm].min()) if tm.any() else LONG_MAX
-                    max_ts = int(we[tm].max()) if tm.any() else 0
-                    min_count = self._host_count
-                    max_count = 0
-                    if is_count.any():
-                        min_count = min(min_count, int(ws[is_count].min()))
-                        max_count = int(we[is_count].max())
-                    cnt_d, results = self._query_rec(
-                        st, self._rec, ws_p, we_p, mask, ic_p,
-                        np.int64(min_ts), np.int64(max_ts),
-                        np.int64(min_count), np.int64(max_count))
-                else:
-                    cnt_d, results = self._query_rec(st, self._rec, ws_p,
-                                                     we_p, mask, ic_p)
-            else:
-                cnt_d, results = self._query(st, ws_p, we_p, mask, ic_p)
+            with span(self.obs, "watermark.query", wm=watermark_ts):
+                cnt_d, results = self._dispatch_query(
+                    st, ws, we, is_count, (ws_p, we_p, mask, ic_p))
 
         if self._has_count:
             self._last_count = self._host_count   # exact host mirror
         bound = (watermark_ts - self.max_lateness) - self.max_fixed_window_size
-        if self._has_count:
-            # records GC in rank-lockstep with the slices (reads the PRE-GC
-            # slice buffer; dispatched before the slice GC)
-            self._rec = self._rec_gc(st, self._rec, np.int64(bound))
-        self._state = self._gc(st, np.int64(bound))
+        with span(self.obs, "watermark.gc", wm=watermark_ts):
+            if self._has_count:
+                # records GC in rank-lockstep with the slices (reads the
+                # PRE-GC slice buffer; dispatched before the slice GC)
+                self._rec = self._rec_gc(st, self._rec, np.int64(bound))
+            self._state = self._gc(st, np.int64(bound))
         self._last_watermark = watermark_ts
         self._trigger_measures = is_count
         return self._wrap_mixed((ws, we, is_count, cnt_d, results),
                                 watermark_ts)
+
+    def _dispatch_query(self, st, ws, we, is_count, padded):
+        """Dispatch the range query over the padded trigger rows
+        ``padded = (ws_p, we_p, mask, ic_p)``; returns device
+        ``(counts, results)``."""
+        if not (self._has_count and self._count_late_seen):
+            return self._query(st, *padded)
+        if not self._grid_spec.has_time_grid:
+            return self._query_rec(st, self._rec, *padded)
+        # the reference final-merge's batch scan bounds
+        # (WindowManager.java:98-118 → LazyAggregateStore.aggregate):
+        # defaults LONG_MAX/0, count default = current count; duplicates
+        # shadow (see build_query)
+        tm = ~is_count
+        min_ts = int(ws[tm].min()) if tm.any() else LONG_MAX
+        max_ts = int(we[tm].max()) if tm.any() else 0
+        min_count = self._host_count
+        max_count = 0
+        if is_count.any():
+            min_count = min(min_count, int(ws[is_count].min()))
+            max_count = int(we[is_count].max())
+        return self._query_rec(st, self._rec, *padded,
+                               np.int64(min_ts), np.int64(max_ts),
+                               np.int64(min_count), np.int64(max_count))
 
     def _wrap_mixed(self, grid, watermark_ts: int):
         """Append context-window sweeps to a grid watermark result when
@@ -1921,16 +1938,21 @@ class TpuWindowOperator(WindowOperator):
     def process_watermark_arrays(self, watermark_ts: int):
         """Synchronous watermark: returns numpy ``(starts[T], ends[T],
         counts[T], [per-agg lowered [T]])`` — one bundled device fetch."""
+        with _obs.program_span(self.obs, "watermark", wm=watermark_ts):
+            return self._watermark_arrays(watermark_ts)
+
+    def _watermark_arrays(self, watermark_ts: int):
         out = self.process_watermark_async(watermark_ts)
         if isinstance(out[0], str) and out[0] == "session":
-            ws, we, cnt, lowered = self._fetch_sessions(out[1])
+            ws, we, cnt, lowered = self._fetch_sessions(out[1], watermark_ts)
             self._trigger_measures = np.zeros((ws.shape[0],), bool)
             self._lat_stamp(_lat.STAGE_EMIT)
             return ws, we, cnt, lowered
         if isinstance(out[0], str) and out[0] == "mixed":
             _, grid, s_outs = out
-            g_ws, g_we, g_cnt, g_low = self._fetch_grid(grid)
-            s_ws, s_we, s_cnt, s_low = self._fetch_sessions(s_outs)
+            g_ws, g_we, g_cnt, g_low = self._fetch_grid(grid, watermark_ts)
+            s_ws, s_we, s_cnt, s_low = self._fetch_sessions(s_outs,
+                                                            watermark_ts)
             ws = np.concatenate([g_ws, s_ws])
             we = np.concatenate([g_we, s_we])
             cnt = np.concatenate([g_cnt, s_cnt])
@@ -1941,13 +1963,14 @@ class TpuWindowOperator(WindowOperator):
                 [is_count, np.zeros((s_ws.shape[0],), bool)])
             self._lat_stamp(_lat.STAGE_EMIT)
             return ws, we, cnt, lowered
-        res = self._fetch_grid(out)
+        res = self._fetch_grid(out, watermark_ts)
         self._lat_stamp(_lat.STAGE_EMIT)
         return res
 
-    def _fetch_grid(self, grid):
+    def _fetch_grid(self, grid, watermark_ts: int):
         import jax
 
+        span = _obs.program_span
         ws, we, is_count, cnt_d, results = grid
         T = ws.shape[0]
         lowered: List[np.ndarray] = [np.empty(0)
@@ -1957,13 +1980,16 @@ class TpuWindowOperator(WindowOperator):
         if T:
             ovf_src = self._state.overflow if self._rec is None \
                 else self._state.overflow | self._rec.overflow
-            cnt_h, res_h, ovf = jax.device_get((cnt_d, results, ovf_src))
+            with span(self.obs, "watermark.fetch", wm=watermark_ts):
+                cnt_h, res_h, ovf = jax.device_get((cnt_d, results,
+                                                    ovf_src))
             self._lat_stamp(_lat.STAGE_DRAIN)
             self._raise_if_overflow(ovf)
-            cnt_np = cnt_h[:T]
-            for agg, res in zip(self.aggregations, res_h):
-                spec = agg.device_spec()
-                lowered.append(np.asarray(spec.lower(res[:T], cnt_np)))
+            with span(self.obs, "watermark.lower", wm=watermark_ts):
+                cnt_np = cnt_h[:T]
+                for agg, res in zip(self.aggregations, res_h):
+                    spec = agg.device_spec()
+                    lowered.append(np.asarray(spec.lower(res[:T], cnt_np)))
         return ws, we, cnt_np, lowered
 
     def _raise_if_overflow(self, ovf) -> None:
@@ -2035,14 +2061,19 @@ class TpuWindowOperator(WindowOperator):
                     self._lat_open = None
                 lat.flush()
 
-    def _fetch_sessions(self, outs):
+    def _fetch_sessions(self, outs, watermark_ts: int):
         """Fetch per-session-window sweep outputs; emission follows window
         registration order (the simulator's context list order)."""
         import jax
 
-        fetched = jax.device_get(
-            (outs, tuple(s.overflow for s in (list(self._session_states)
-                                              + list(self._ctx_states)))))
+        with _obs.program_span(self.obs, "watermark.fetch", wm=watermark_ts):
+            fetched = jax.device_get(
+                (outs, tuple(s.overflow for s in (
+                    list(self._session_states) + list(self._ctx_states)))))
+        with _obs.program_span(self.obs, "watermark.lower", wm=watermark_ts):
+            return self._lower_sessions(fetched)
+
+    def _lower_sessions(self, fetched):
         self._lat_stamp(_lat.STAGE_DRAIN)
         gap_outs, ovfs = fetched
         for ovf in ovfs:

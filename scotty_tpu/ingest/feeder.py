@@ -127,21 +127,15 @@ class DeviceRingFeeder:
         self.pace_steps = pace_steps
         self._staged: deque = deque()   # (blk, v_dev, t_dev)
         self._since_pace = 0
-        # prefetch-overlap accounting (host seconds; the bench reports
-        # overlap_ratio = 1 - wait / (stage + dispatch + wait): 1.0 means
-        # every transfer finished behind compute, 0 means every transfer
-        # was waited out in the open)
-        self.stage_s = 0.0
-        self.dispatch_s = 0.0
-        self.wait_s = 0.0
+        import jax
 
-    def overlap_ratio(self) -> float:
-        total = self.stage_s + self.dispatch_s + self.wait_s
-        return 1.0 - (self.wait_s / total) if total > 0 else 1.0
+        # the CPU backend's device_put of an aligned numpy array is
+        # zero-copy: the device array would alias the slot, which
+        # recycles to the producer before the async ingest has read it
+        self._copy_slot = jax.default_backend() == "cpu"
 
     def _stage(self, blk: RingBlock) -> None:
         import jax
-        import time
 
         n, B = blk.n, self.ring.block_size
         if n == 0:
@@ -152,36 +146,34 @@ class DeviceRingFeeder:
             # contract) — the slot's tail still holds a previous block
             blk.ts[n:] = blk.ts[n - 1]
             blk.vals[n:] = 0.0
-        t0 = time.perf_counter()
-        v_dev = jax.device_put(blk.vals)
-        t_dev = jax.device_put(blk.ts)
-        self.stage_s += time.perf_counter() - t0
+        with _obs.program_span(getattr(self.op, "obs", None),
+                               "ingest.stage",
+                               bytes=blk.vals.nbytes + blk.ts.nbytes):
+            vals, ts = blk.vals, blk.ts
+            if self._copy_slot:
+                vals, ts = vals.copy(), ts.copy()
+            v_dev = jax.device_put(vals)
+            t_dev = jax.device_put(ts)
         self._staged.append((blk, v_dev, t_dev))
 
     def _dispatch_oldest(self) -> int:
-        import time
-
         op_obs = getattr(self.op, "obs", None)
         if op_obs is not None and op_obs.latency is not None:
             # ring-dequeue pre-stamp (ISSUE 14): the oldest staged
             # block's ingest is about to dispatch
             op_obs.latency.pre(_lat.STAGE_RING_DEQUEUE)
         blk, v_dev, t_dev = self._staged.popleft()
-        t0 = time.perf_counter()
         if self.shaper is not None:
             self.shaper.shape_device_batch(v_dev, t_dev, blk.ts_min,
                                            blk.ts_max, n_valid=blk.n)
         else:
             self.op.ingest_device_batch(v_dev, t_dev, blk.ts_min,
                                         blk.ts_max, n_valid=blk.n)
-        t1 = time.perf_counter()
         # the slot's numpy buffer recycles to the producer: wait for the
         # TRANSFER only (the ingest dispatch above stays async)
-        v_dev.block_until_ready()
-        t_dev.block_until_ready()
-        t2 = time.perf_counter()
-        self.dispatch_s += t1 - t0
-        self.wait_s += t2 - t1
+        with _obs.program_span(op_obs, "ingest.transfer_wait"):
+            v_dev.block_until_ready()
+            t_dev.block_until_ready()
         self.ring.free(blk)
         self._since_pace += 1
         if self.pace_steps is not None \
@@ -189,9 +181,8 @@ class DeviceRingFeeder:
             self._since_pace = 0
             state = getattr(self.op, "_state", None)
             if state is not None:
-                t3 = time.perf_counter()
-                state.n_slices.block_until_ready()
-                self.wait_s += time.perf_counter() - t3
+                with _obs.program_span(op_obs, "ingest.transfer_wait"):
+                    state.n_slices.block_until_ready()
         return 1
 
     def pump(self, limit: Optional[int] = None) -> int:
@@ -345,10 +336,11 @@ class RingIngestor:
         # freeing operation is timed — the wait IS the backpressure, and
         # a long one is a flagged consumer stall (PR 3 watchdog)
         t0 = self.clock.now()
-        self.feeder.pump()
-        freed = True
-        if not self.ring.has_space():
-            freed = bool(self.feeder.reclaim(1))
+        with _obs.program_span(self.obs, "ingest.ring_full"):
+            self.feeder.pump()
+            freed = True
+            if not self.ring.has_space():
+                freed = bool(self.feeder.reclaim(1))
         gap = self.clock.now() - t0
         if self.stall_timeout_s is not None and gap > self.stall_timeout_s:
             flag_stall(self.obs, "ingest_ring_consumer", gap,

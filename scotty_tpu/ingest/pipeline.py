@@ -30,6 +30,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import obs as _obs
 from ..obs import latency as _lat
 from ..resilience.clock import Clock, SystemClock
 from .feeder import DeviceRingFeeder, RingIngestor
@@ -125,8 +126,9 @@ class LineRateFeed:
             # record-arrival pre-stamp (ISSUE 14): the line-rate feed
             # IS the connector boundary for externally-fed streams
             self.obs.latency.pre(_lat.STAGE_ARRIVAL)
-        self.accumulator.offer_block(vals, ts)
-        self._propagate_deadline()
+        with _obs.program_span(self.obs, "ingest.offer", n=len(ts)):
+            self.accumulator.offer_block(vals, ts)
+            self._propagate_deadline()
 
     def poll(self) -> None:
         """Idle tick: evaluate the bounded-delay deadline + move committed
@@ -155,5 +157,4 @@ class LineRateFeed:
     def snapshot(self) -> dict:
         snap = self.ingestor.snapshot()
         snap["accumulator_held"] = self.accumulator.held
-        snap["prefetch_overlap_ratio"] = self.feeder.overlap_ratio()
         return snap

@@ -192,7 +192,7 @@ from .device import (
 from .exporters import JsonlExporter, prometheus_text, write_chrome_trace
 from .flight import FLIGHT_DROPPED_EVENTS, FlightRecorder, write_postmortem
 from .server import HEALTH_CHECKS, HEALTH_UNHEALTHY, HealthPolicy
-from .spans import Span, SpanRecorder
+from .spans import Span, SpanRecorder, program_span
 
 # stable metric names (the contract above)
 INGEST_TUPLES = "ingest_tuples"
@@ -583,9 +583,10 @@ METRIC_HELP = {
 class Observability:
     """One registry + span recorder, shared by every layer of a run.
 
-    ``annotate=True`` additionally opens a ``jax.profiler.TraceAnnotation``
-    per span, so the same phase names appear inside captured device traces
-    (:func:`scotty_tpu.utils.profiling.trace`).
+    Every span also opens a ``jax.profiler.TraceAnnotation`` named
+    ``scotty.<name>``, so the same phases appear inside captured device
+    traces (:func:`scotty_tpu.utils.profiling.trace`); without a profiler
+    session the annotation is inert.
 
     ``flight`` attaches a :class:`.flight.FlightRecorder`: spans then
     also land open/close events in the ring, registry activity is sampled
@@ -597,13 +598,12 @@ class Observability:
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
                  spans: Optional[SpanRecorder] = None,
-                 annotate: bool = False,
                  flight: Optional[FlightRecorder] = None,
                  postmortem_dir: Optional[str] = None,
                  latency=None, workload=None, slo=None,
                  attribution=None):
         self.registry = registry or MetricsRegistry()
-        self.spans = spans or SpanRecorder(annotate=annotate)
+        self.spans = spans or SpanRecorder()
         self.flight = flight
         self.postmortem_dir = postmortem_dir
         #: emission-latency tracer (ISSUE 14): None by default — every
@@ -633,19 +633,19 @@ class Observability:
         self.flight_hook = None
 
     # -- recording --------------------------------------------------------
-    def span(self, name: str):
+    def span(self, name: str, **args):
         if self.flight is None:
-            return self.spans.span(name)
-        return self._flight_span(name)
+            return self.spans.span(name, **args)
+        return self._flight_span(name, args)
 
     @contextlib.contextmanager
-    def _flight_span(self, name: str):
+    def _flight_span(self, name: str, args: dict):
         from . import flight as _flight
 
         self.flight.record(_flight.SPAN_OPEN, name)
         try:
-            with self.spans.span(name):
-                yield
+            with self.spans.span(name, **args) as ann:
+                yield ann
         finally:
             self.flight.record(_flight.SPAN_CLOSE, name)
 
@@ -856,7 +856,7 @@ class Observability:
 
 __all__ = [
     "Observability", "MetricsRegistry", "SpanRecorder", "Span",
-    "JsonlExporter", "prometheus_text", "write_chrome_trace",
+    "program_span", "JsonlExporter", "prometheus_text", "write_chrome_trace",
     "FlightRecorder", "write_postmortem", "HealthPolicy",
     "FLIGHT_DROPPED_EVENTS", "HEALTH_CHECKS", "HEALTH_UNHEALTHY",
     "METRIC_HELP",

@@ -2,10 +2,14 @@
 
 ``SpanRecorder.span("ingest")`` times a host-side phase; spans nest (a
 per-thread stack tracks depth/parentage) and export as Chrome-trace /
-Perfetto JSON (``chrome://tracing``, https://ui.perfetto.dev). Optionally
-each span also opens a ``jax.profiler.TraceAnnotation`` (via
-:func:`scotty_tpu.utils.profiling.annotate`) so the same phase names show
-up inside a captured device trace.
+Perfetto JSON (``chrome://tracing``, https://ui.perfetto.dev). Every span
+also opens a ``jax.profiler.TraceAnnotation`` named ``scotty.<name>``, so
+the same phases sit on the profiler's clock inside a captured device
+trace; without an active profiler session the annotation is inert.
+
+:func:`program_span` is the one face the served path uses: the
+annotation always, plus the recorded :class:`Span` when an
+:class:`~scotty_tpu.obs.Observability` is attached.
 
 Host wall-time only by design: nothing here may enter a jitted code path —
 spans wrap *dispatch* regions, and device time is attributed by the
@@ -18,7 +22,26 @@ import contextlib
 import json
 import threading
 import time
-from typing import Iterator, List, Optional
+from typing import List
+
+PREFIX = "scotty."
+
+
+def _annotation(name: str, args: dict):
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(PREFIX + name, **args)
+
+
+def program_span(obs, name: str, **args):
+    """Context manager around one stage of the served path: a profiler
+    annotation ``scotty.<name>`` carrying ``args`` (inert without a
+    profiler session), recorded in ``obs``'s span recorder too when
+    ``obs`` is not None. It yields the annotation, whose
+    ``set_metadata(**more)`` adds args known only inside the span."""
+    if obs is None:
+        return _annotation(name, args)
+    return obs.span(name, **args)
 
 
 class Span:
@@ -45,15 +68,13 @@ class SpanRecorder:
     ``max_spans`` (oldest kept — a runaway per-interval span loop must not
     grow without limit, mirroring the bounded metrics reservoir)."""
 
-    def __init__(self, annotate: bool = False, max_spans: int = 65536,
-                 clock=time.perf_counter):
+    def __init__(self, max_spans: int = 65536, clock=time.perf_counter):
         self._clock = clock
         self._epoch = clock()
         self._lock = threading.Lock()
         self._local = threading.local()
         self._dropped = 0
         self.max_spans = int(max_spans)
-        self.annotate = annotate
         self.spans: List[Span] = []
 
     def _stack(self) -> list:
@@ -63,40 +84,29 @@ class SpanRecorder:
         return st
 
     @contextlib.contextmanager
-    def span(self, name: str) -> Iterator[None]:
+    def span(self, name: str, **args):
         """Time a phase. Nested calls record increasing ``depth``; the
         inner span closes (and is appended) before the outer one, so
-        Chrome-trace viewers reconstruct the flame from timestamps."""
+        Chrome-trace viewers reconstruct the flame from timestamps.
+        ``args`` ride on the phase's profiler annotation, which the
+        context manager yields."""
         stack = self._stack()
         depth = len(stack)
         stack.append(name)
-        ann = None
-        if self.annotate:
+        with _annotation(name, args) as ann:
+            t0 = self._clock()
             try:
-                from ..utils.profiling import annotate as _annotate
-
-                ann = _annotate(name)
-                ann.__enter__()
-            # scotty: allow(silent-drop) — profiler-optional fallback:
-            # without jax.profiler the span still records host-side;
-            # no event or tuple is lost
-            except Exception:
-                ann = None
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            dur = self._clock() - t0
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            stack.pop()
-            with self._lock:
-                if len(self.spans) < self.max_spans:
-                    self.spans.append(Span(
-                        name, t0 - self._epoch, dur, depth,
-                        threading.get_ident()))
-                else:
-                    self._dropped += 1
+                yield ann
+            finally:
+                dur = self._clock() - t0
+                stack.pop()
+                with self._lock:
+                    if len(self.spans) < self.max_spans:
+                        self.spans.append(Span(
+                            name, t0 - self._epoch, dur, depth,
+                            threading.get_ident()))
+                    else:
+                        self._dropped += 1
 
     def record_span(self, name: str, t0_rel: float, dur: float,
                     depth: int = 0) -> None:

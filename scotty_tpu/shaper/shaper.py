@@ -334,19 +334,20 @@ class StreamShaper:
                     # shaper — disable for the run, count once
                     self._pallas_sort = False
                     _pl.record_fallback(self.obs, "sort_split_shape")
-        if kern is not None:
-            from .. import pallas as _pl
+        with _obs.program_span(self.obs, "shaper.split"):
+            if kern is not None:
+                from .. import pallas as _pl
 
-            _pl.record_dispatch(self.obs)
-            (self._dev_stats, io_ts, io_vals, io_valid,
-             l_ts, l_vals, l_valid) = kern(
-                 self._dev_stats, ts, vals, valid, cut, seed,
-                 np.int64(ts_min))
-        else:
-            kern = _dev.sort_split_kernel(B, self.late_capacity)
-            (self._dev_stats, io_ts, io_vals, io_valid,
-             l_ts, l_vals, l_valid) = kern(self._dev_stats, ts, vals,
-                                           valid, cut, seed)
+                _pl.record_dispatch(self.obs)
+                (self._dev_stats, io_ts, io_vals, io_valid,
+                 l_ts, l_vals, l_valid) = kern(
+                     self._dev_stats, ts, vals, valid, cut, seed,
+                     np.int64(ts_min))
+            else:
+                kern = _dev.sort_split_kernel(B, self.late_capacity)
+                (self._dev_stats, io_ts, io_vals, io_valid,
+                 l_ts, l_vals, l_valid) = kern(self._dev_stats, ts, vals,
+                                               valid, cut, seed)
         if not late_possible:
             # provably nothing late: the sorted batch is fully in-order
             op.ingest_device_batch(io_vals, io_ts, ts_min, ts_max,

@@ -27,11 +27,11 @@ from .metrics import (
     MetricsRegistry,
     ThroughputLogger,
 )
-from .profiling import analyze_log, annotate, trace
+from .profiling import analyze_log, trace
 
 __all__ = [
     "REGISTRY", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "ThroughputLogger", "analyze_log", "stdout_echo",
-    "annotate", "trace", "restore_engine_operator", "restore_host_operator",
+    "trace", "restore_engine_operator", "restore_host_operator",
     "save_engine_operator", "save_host_operator",
 ]

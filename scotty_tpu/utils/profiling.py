@@ -25,16 +25,6 @@ def trace(log_dir: Optional[str] = None) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named sub-span (jax.profiler.TraceAnnotation) for phase attribution:
-    ingest / query / gc."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
 _RATE_RE = re.compile(r"That's ([\d,]+) elements/second/chip")
 
 

@@ -843,7 +843,6 @@ def test_ingest_external_runner_cell_smoke():
     res = run_ingest_external_cell(cfg, "Sliding(2000,500)", "sum")
     assert res.tuples_per_sec > 0
     assert res.speedup_vs_per_record > 0
-    assert 0.0 <= res.prefetch_overlap_ratio <= 1.0
     assert res.ring_shed == 0
     assert res.ring_occupancy_p99 >= res.ring_occupancy_p50 >= 0
 
