@@ -564,6 +564,33 @@ def build_ingest(spec: EngineSpec, capacity: int, annex_capacity: int,
     return ingest
 
 
+#: Largest run bound whose dense-ingest fold is a one-hot product over the
+#: [B, R] lane-by-run grid; above it the fold is a segmented scan, whose
+#: cost does not grow with R.
+ONE_HOT_FOLD_RUNS = 16
+
+_SEGMENT_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+
+def _segment_fold(kind: str, k: jnp.ndarray, lifted: jnp.ndarray,
+                  last: jnp.ndarray) -> jnp.ndarray:
+    """Per-run combine of ``lifted[B, w]`` under the sorted run ids
+    ``k[B]``: a segmented inclusive scan (each run restarts at its first
+    lane), read at each run's last lane ``last[R]`` -> ``[R, w]``. Sums
+    add in float32 along the scan's tree; min/max are exact."""
+    op = _SEGMENT_OPS[kind]
+    head = jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
+
+    def combine(a, b):
+        a_head, a_val = a
+        b_head, b_val = b
+        return a_head | b_head, jnp.where(b_head[:, None], b_val,
+                                          op(a_val, b_val))
+
+    _, acc = jax.lax.associative_scan(combine, (head, lifted))
+    return acc[jnp.clip(last, 0, k.shape[0] - 1)]
+
+
 def build_ingest_dense(spec: EngineSpec, capacity: int, runs: int,
                        pallas_fold: bool = False,
                        pallas_packed: bool = False):
@@ -578,10 +605,13 @@ def build_ingest_dense(spec: EngineSpec, capacity: int, runs: int,
 
     * run boundaries: two vmapped ``searchsorted`` over the sorted run ids
       + gathers (t_last = ts at a run's last lane; start/end at its first),
-    * sum-like partials: a [B, R] one-hot matmul (MXU),
-    * min/max partials: a masked [B, R, w] reduction,
-    * one tiny [R]-lane scatter per field into the buffer (R ≈ 8-64 rows vs
-      B = 1M lanes — three orders of magnitude fewer scatter lanes).
+    * partials, for ``runs <= ONE_HOT_FOLD_RUNS``: a [B, R] one-hot matmul
+      at ``HIGHEST`` precision (float32 sums on the MXU) for sum-like
+      aggregations, a masked [B, R, w] reduction for min/max; for larger
+      bounds a segmented scan read at each run's last lane
+      (:func:`_segment_fold`), which builds no [B, R] temporary,
+    * one [R]-lane scatter per field into the buffer (R rows vs B lanes —
+      two to four orders of magnitude fewer scatter lanes).
 
     Contract (host-checked): ts ascending, all ts >= max_event_time, no
     count-measure or session windows, dense-lift aggregations, and the
@@ -648,9 +678,16 @@ def build_ingest_dense(spec: EngineSpec, capacity: int, runs: int,
                 # branches below
                 upd = fold(k, lifted).astype(part.dtype)
                 part = _combine_scatter(part, rows, upd, agg.kind)
+            elif R > ONE_HOT_FOLD_RUNS:
+                ident = jnp.asarray(agg.identity, part.dtype)
+                upd = _segment_fold(agg.kind, k, lifted.astype(part.dtype),
+                                    last)                    # [R, w]
+                upd = jnp.where(live[:, None], upd, ident)
+                part = _combine_scatter(part, rows, upd, agg.kind)
             elif agg.kind == "sum":
                 oh = (k[:, None] == r_idx[None, :]).astype(part.dtype)
-                upd = oh.T @ lifted                          # [R, w] — MXU
+                upd = jnp.matmul(oh.T, lifted,               # [R, w] — MXU
+                                 precision=jax.lax.Precision.HIGHEST)
                 upd = jnp.where(live[:, None], upd, 0)
                 part = part.at[rows].add(upd)
             else:

@@ -256,6 +256,14 @@ def _dense_kernel(spec, capacity: int, runs: int,
     return hit
 
 
+#: Run bounds of the dense ingest kernel above the first rung
+#: (``EngineConfig.dense_ingest_runs``): an in-order batch takes the
+#: smallest rung its span fits, one cached executable per rung (each one
+#: more compile at set-up, so the rungs are few and far apart); a batch
+#: over the last rung takes the general in-order kernel.
+DENSE_RUN_LADDER = (256, 4096)
+
+
 def dense_eligible(spec) -> bool:
     """Static part of the dense-ingest decision: no count/session windows,
     dense-lift aggregations only."""
@@ -459,8 +467,7 @@ class TpuWindowOperator(WindowOperator):
          self._count_at_rec, self._ingest_cut,
          self._ingest_rows) = _kernels(self._grid_spec, C, A, RCap)
         # the dense fast path closes over the union grid too
-        self._dense_runs = self.config.dense_ingest_runs \
-            if dense_eligible(self._grid_spec) else 0
+        self._dense_rungs = self._dense_ladder()
         self._min_grid = min_grid_period(self._grid_spec)
         self._ingest_dense = None
 
@@ -772,10 +779,10 @@ class TpuWindowOperator(WindowOperator):
             elif isinstance(w, (ForwardContextAware, ForwardContextFree)):
                 self._ctx_order.append(("g", gi))
                 gi += 1
-        self._dense_runs = self.config.dense_ingest_runs \
-            if (self._has_grid and dense_eligible(self._grid_spec)) else 0
+        self._dense_rungs = self._dense_ladder()
         self._min_grid = min_grid_period(self._grid_spec)
-        self._ingest_dense = None       # built lazily on first eligible batch
+        # {runs: kernel}, built (every rung) on the first eligible batch
+        self._ingest_dense = None
         self._last_count = 0
         self._host_met = None           # host mirror of max event time
         self._host_min_ts = None        # host mirror of min event time
@@ -1115,8 +1122,8 @@ class TpuWindowOperator(WindowOperator):
                 io_v[n_io:] = 0
                 io_valid = np.zeros((B,), bool)
                 io_valid[:n_io] = True
-                kern = self._pick_inorder_kernel(int(io_t[0]),
-                                                 int(io_t[n_io - 1]))
+                kern, _ = self._pick_inorder_kernel(int(io_t[0]),
+                                                    int(io_t[n_io - 1]))
                 self._state = kern(self._state, io_t, io_v, io_valid)
 
                 lt = np.empty((late_cap,), np.int64)
@@ -1130,7 +1137,7 @@ class TpuWindowOperator(WindowOperator):
                 return
             self._state = self._ingest(self._state, batch_t, batch_v, valid)
             return
-        kern = self._pick_inorder_kernel(
+        kern, _ = self._pick_inorder_kernel(
             int(batch_t[0]) if take else 0,
             int(batch_t[take - 1]) if take else 0)
         self._state = kern(self._state, batch_t, batch_v, valid)
@@ -1337,26 +1344,40 @@ class TpuWindowOperator(WindowOperator):
                             self.obs.counter(
                                 CTX_SPECULATIVE_FALLBACKS).inc()
 
-    def _pick_inorder_kernel(self, ts_lo: int, ts_hi: int):
-        """Scatter-free dense kernel when the batch's slice-run count is
-        provably under the bound; general in-order kernel otherwise."""
-        pf = bool(getattr(self.config, "pallas_slice_merge", False))
-        if self._dense_runs:
-            runs = (ts_hi - ts_lo) // self._min_grid + 3
-            if runs <= self._dense_runs:
-                if self._ingest_dense is None:
-                    self._ingest_dense = _dense_kernel(
-                        self._grid_spec, self.config.capacity,
-                        self._dense_runs, pallas_fold=pf,
-                        pallas_packed=pf and bool(getattr(
-                            self.config, "pallas_packed", False)))
-                if pf:
-                    # picked once per dispatched batch — the host-side
-                    # dispatch count of Pallas-bearing programs
-                    from .. import pallas as _pl
+    def _dense_ladder(self) -> tuple:
+        """The run bounds the dense ingest kernel is built at:
+        ``EngineConfig.dense_ingest_runs`` (0: no dense ingest), then the
+        larger rungs of ``DENSE_RUN_LADDER`` — or the first rung alone
+        under ``pallas_slice_merge`` (the Pallas segment fold unrolls its
+        run loop, so its cost grows with the bound)."""
+        first = self.config.dense_ingest_runs
+        if not (first and self._has_grid
+                and dense_eligible(self._grid_spec)):
+            return ()
+        if getattr(self.config, "pallas_slice_merge", False):
+            return (first,)
+        return (first,) + tuple(r for r in DENSE_RUN_LADDER if r > first)
 
-                    _pl.record_dispatch(self.obs)
-                return self._ingest_dense
+    def _pick_inorder_kernel(self, ts_lo: int, ts_hi: int):
+        """``(kernel, runs)`` for an in-order batch spanning
+        ``[ts_lo, ts_hi]``: the scatter-free dense kernel at the smallest
+        rung of the run ladder that provably bounds the batch's slice
+        runs, or the general in-order kernel and 0 above the last rung."""
+        pf = bool(getattr(self.config, "pallas_slice_merge", False))
+        need = (ts_hi - ts_lo) // self._min_grid + 3
+        runs = next((r for r in self._dense_rungs if need <= r), 0)
+        if runs:
+            if self._ingest_dense is None:
+                self._build_dense(pf)
+            if pf:
+                # picked once per dispatched batch — the host-side
+                # dispatch count of Pallas-bearing programs
+                from .. import pallas as _pl
+
+                _pl.record_dispatch(self.obs)
+            if self.obs is not None:
+                self.obs.counter(_obs.INGEST_DENSE_BATCHES).inc()
+            return self._ingest_dense[runs], runs
         if pf:
             # a flagged batch over the runs bound (or dense ingest
             # disabled) degrades to the scatter-heavy general kernel —
@@ -1365,7 +1386,27 @@ class TpuWindowOperator(WindowOperator):
             from .. import pallas as _pl
 
             _pl.record_fallback(self.obs, "dense_runs_bound")
-        return self._ingest_inorder
+        return self._ingest_inorder, 0
+
+    def _build_dense(self, pf: bool) -> None:
+        """Build the dense kernel at every rung and compile each now, by
+        one dispatch on the live state of a batch with no valid lane (a
+        no-op on the state): the first batch that needs a larger rung,
+        possibly mid-stream, then compiles nothing. No host sync."""
+        import jax
+
+        B = self.config.batch_size
+        self._ingest_dense = {
+            R: _dense_kernel(self._grid_spec, self.config.capacity, R,
+                             pallas_fold=pf,
+                             pallas_packed=pf and bool(getattr(
+                                 self.config, "pallas_packed", False)))
+            for R in self._dense_rungs}
+        ts, vals, valid = jax.device_put((np.zeros((B,), np.int64),
+                                          np.zeros((B,), np.float32),
+                                          np.zeros((B,), bool)))
+        for kern in self._ingest_dense.values():
+            self._state = kern(self._state, ts, vals, valid)
 
     # -- overflow policy (resilience.policy) -------------------------------
     #: admission slack: slices the mirror always keeps free so an exact
@@ -1629,12 +1670,15 @@ class TpuWindowOperator(WindowOperator):
             else min(self._host_min_ts, ts_min)
         self._host_count += n
         if has_late:
-            kern = self._ingest         # general kernel: late/annex paths
+            # general kernel: late/annex paths
+            kern, kind, runs = self._ingest, "general", 0
         else:
             # dense scatter-free variant when the span bound allows
-            kern = self._pick_inorder_kernel(ts_min, ts_max)
+            kern, runs = self._pick_inorder_kernel(ts_min, ts_max)
+            kind = "dense" if runs else "inorder"
         with _obs.program_span(self.obs, "ingest.dispatch", lanes=B,
-                               n_valid=n, late=has_late):
+                               n_valid=n, late=has_late, kernel=kind,
+                               runs=runs):
             self._state = kern(self._state, ts, vals, valid)
         if self._has_count:
             # device batches with count windows are in-order by contract
@@ -1670,7 +1714,8 @@ class TpuWindowOperator(WindowOperator):
             else min(self._host_min_ts, ts_min)
         self._host_count += n
         with _obs.program_span(self.obs, "ingest.dispatch",
-                               lanes=ts.shape[0], n_valid=n, late=True):
+                               lanes=ts.shape[0], n_valid=n, late=True,
+                               kernel="general", runs=0):
             self._state = self._ingest(self._state, ts, vals, valid)
 
     # -- watermark ---------------------------------------------------------

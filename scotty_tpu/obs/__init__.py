@@ -36,6 +36,7 @@ Stable metric-name contract (documented in README.md / docs/API.md):
 ========================  ====================================================
 ``ingest_tuples``         counter: tuples accepted (operator or connector)
 ``ingest_batch_size``     histogram: tuples per host batch
+``ingest_dense_batches``  counter: in-order batches given the dense kernel
 ``late_tuples``           counter: tuples arriving below the stream's max ts
 ``dropped_tuples``        counter: tuples older than watermark - lateness
 ``watermarks``            counter: watermark advances
@@ -197,6 +198,7 @@ from .spans import Span, SpanRecorder, program_span
 # stable metric names (the contract above)
 INGEST_TUPLES = "ingest_tuples"
 INGEST_BATCH_SIZE = "ingest_batch_size"
+INGEST_DENSE_BATCHES = "ingest_dense_batches"
 LATE_TUPLES = "late_tuples"
 DROPPED_TUPLES = "dropped_tuples"
 WATERMARKS = "watermarks"
@@ -412,6 +414,8 @@ AUTOTUNE_RETUNE_SPAN = "autotune_retune"
 METRIC_HELP = {
     INGEST_TUPLES: "tuples accepted (operator or connector boundary)",
     INGEST_BATCH_SIZE: "tuples per host batch",
+    INGEST_DENSE_BATCHES: "in-order batches ingested by the scatter-free "
+                          "dense kernel",
     LATE_TUPLES: "tuples arriving below the stream's max event time",
     DROPPED_TUPLES: "tuples older than watermark - allowed lateness",
     WATERMARKS: "watermark advances",
@@ -864,7 +868,8 @@ __all__ = [
     "DEVICE_INGEST_TUPLES", "DEVICE_LATE_TUPLES", "DEVICE_DROPPED_TUPLES",
     "DEVICE_TRIGGERS_FIRED", "DEVICE_WINDOWS_NONEMPTY",
     "DEVICE_SLICES_TOUCHED", "DEVICE_SILENT_INTERVALS",
-    "INGEST_TUPLES", "INGEST_BATCH_SIZE", "LATE_TUPLES", "DROPPED_TUPLES",
+    "INGEST_TUPLES", "INGEST_BATCH_SIZE", "INGEST_DENSE_BATCHES",
+    "LATE_TUPLES", "DROPPED_TUPLES",
     "WATERMARKS", "WATERMARK_LAG_MS", "WATERMARK_DISPATCH_MS",
     "INTERVAL_STEP_MS", "SYNC_MS", "SLICE_OCCUPANCY", "SLICE_HEADROOM",
     "QUEUE_DEPTH", "WINDOWS_EMITTED", "OVERFLOWS", "SILENT_INTERVALS",

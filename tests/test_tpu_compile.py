@@ -4,7 +4,8 @@ Interpret-mode tests cannot see what the TPU compiler refuses (block
 shapes off the (8, 128) tiling, primitives Mosaic cannot lower, programs
 that do not fit the device). Here each program is lowered at the width a
 deployment runs and compiled for a ``v5e:2x2`` topology: the four Pallas
-kernels with ``interpret=False``, the ``entry()`` ingest step, the
+kernels with ``interpret=False``, the dense ingest kernel at each rung
+of the operator's run ladder, the ``entry()`` ingest step, the
 headline ``AlignedStreamPipeline`` step at capacity ``1 << 17``, and the
 mesh keyed step over the four described chips. Nothing runs; a pass says
 the chip's compiler accepts the program, not that it is right or fast.
@@ -139,6 +140,29 @@ def test_segment_fold_compiles(one_chip, runs, width):
     c = _compile(fold, _sds((B,), jnp.int32, one_chip),
                  _sds((B, width), jnp.float32, one_chip))
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("runs", [16, 256, 4096])
+def test_dense_ingest_rung_compiles(one_chip, runs):
+    """The operator's dense in-order ingest at each rung of its run
+    ladder, at the benchmark cells' capacity and batch, sum+min+max."""
+    import jax
+    import jax.numpy as jnp
+
+    from scotty_tpu import MaxAggregation, MinAggregation, SumAggregation
+    from scotty_tpu.engine import core as ec
+
+    spec = ec.EngineSpec(
+        periods=(1,), bands=(), count_periods=(),
+        aggs=tuple(a().device_spec() for a in (
+            SumAggregation, MinAggregation, MaxAggregation)))
+    C, A, B = 1 << 17, 1 << 12, 1 << 18
+    state = _shapes(jax.eval_shape(lambda: ec.init_state(spec, C, A)),
+                    one_chip)
+    _compile(ec.build_ingest_dense(spec, C, runs), state,
+             _sds((B,), jnp.int64, one_chip),
+             _sds((B,), jnp.float32, one_chip),
+             _sds((B,), jnp.bool_, one_chip))
 
 
 # -- whole XLA steps ---------------------------------------------------------
