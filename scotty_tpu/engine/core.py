@@ -591,9 +591,64 @@ def _segment_fold(kind: str, k: jnp.ndarray, lifted: jnp.ndarray,
     return acc[jnp.clip(last, 0, k.shape[0] - 1)]
 
 
+I32_MIN, I32_MAX = -(1 << 31), (1 << 31) - 1
+
+
+def ts32_fits(spec: EngineSpec, ts_lo: int, ts_hi: int) -> bool:
+    """Whether a batch spanning ``[ts_lo, ts_hi]`` keeps every int32
+    quantity of the narrow dense kernel (``build_ingest_dense(ts32=True)``)
+    exact: the offsets ``ts - ts_lo``, each offset plus its period residue,
+    and the batch's grid starts relative to ``ts_lo``. A grid period
+    ``p`` bounds how far a grid start lies before its timestamp
+    (``p - 1``); with bands alone the bound is the first lane's own
+    distance to its band edge."""
+    if ts_lo < 0:
+        return False
+    reach = max([int(p) for p in spec.periods]
+                + [int(p) for p, _ in spec.offset_periods], default=0)
+    if not reach:
+        reach = ts_lo - int(host_grid_start(spec, np.int64([ts_lo]))[0])
+    return ts_hi - ts_lo + reach < (1 << 31)
+
+
+def _rebase32(t0: jnp.ndarray, x) -> jnp.ndarray:
+    """``x - t0`` as int32, clamped to the int32 range (a point far before
+    or after the batch compares the same way as its clamped offset)."""
+    return jnp.clip(x - t0, I32_MIN, I32_MAX).astype(jnp.int32)
+
+
+def _grid_offset32(spec: EngineSpec, d: jnp.ndarray,
+                   t0: jnp.ndarray) -> jnp.ndarray:
+    """``grid_start(spec, t0 + d) - t0`` in int32, for offsets ``d[B]``
+    from ``t0`` under the :func:`ts32_fits` test: each period's residue
+    ``t0 mod p`` is one scalar int64 op, and ``ts mod p == (d + t0 mod p)
+    mod p`` keeps the per-lane work in int32. The clamp to >= 0 is the
+    rebased zero."""
+    zero = _rebase32(t0, jnp.int64(0))
+    cands = [jnp.broadcast_to(zero, d.shape)]
+    if spec.periods:
+        pall = np.asarray(sorted(set(spec.periods)), dtype=np.int64)
+        for i in range(0, len(pall), 128):
+            p = pall[i:i + 128]
+            r = jnp.mod(t0, jnp.asarray(p)).astype(jnp.int32)
+            p32 = jnp.asarray(p.astype(np.int32))
+            cands.append(jnp.max(
+                d[:, None] - jax.lax.rem(d[:, None] + r[None, :],
+                                         p32[None, :]), axis=1))
+    for (p, r) in spec.offset_periods:
+        q = jnp.mod(t0 - r, p).astype(jnp.int32)
+        cands.append(d - jax.lax.rem(d + q, jnp.int32(p)))
+    for (bs, bsz) in spec.bands:
+        lo = _rebase32(t0, jnp.int64(bs))
+        hi = _rebase32(t0, jnp.int64(bs + bsz))
+        cands.append(jnp.where(d >= hi, hi, jnp.where(d >= lo, lo, zero)))
+    return functools.reduce(jnp.maximum, cands)
+
+
 def build_ingest_dense(spec: EngineSpec, capacity: int, runs: int,
                        pallas_fold: bool = False,
-                       pallas_packed: bool = False):
+                       pallas_packed: bool = False,
+                       ts32: bool = False):
     """In-order ingest without large scatters — the keyed/batched fast path.
 
     int64 scatters cost ~100 ms per 1M lanes on v5e (no native int64: XLA
@@ -626,22 +681,42 @@ def build_ingest_dense(spec: EngineSpec, capacity: int, runs: int,
     [R]-lane buffer scatter stays. Default OFF keeps this builder's
     lowering byte-identical. ``pallas_packed`` streams the lifted
     values as bf16 (toleranced, see ``pallas.packed_tolerance``).
+
+    ``ts32=True`` does the per-lane timestamp arithmetic in int32, on
+    offsets from the batch's first timestamp (:func:`_grid_offset32`): on
+    v5e, which has no 64-bit integer unit, the int64 grid-start ``mod``
+    over every lane is the kernel's largest op. Only ``[R]``-lane and
+    scalar work stays int64; the state after each batch is bit-identical
+    to the default form's. Its contract adds the :func:`ts32_fits` span
+    test, a valid prefix, and ``max_event_time`` read at the last valid
+    lane. Default OFF keeps the int64 form's lowering byte-identical.
     """
     C, R = capacity, runs
 
     def ingest(state: SliceBufferState, ts: jnp.ndarray, vals: jnp.ndarray,
                valid: jnp.ndarray) -> SliceBufferState:
         B = ts.shape[0]
-        s = grid_start(spec, ts)
+        if ts32:
+            # the low words' difference is ts - t0 exactly (ts32_fits)
+            t0 = ts[0]
+            s = _grid_offset32(spec, ts.astype(jnp.int32)
+                               - t0.astype(jnp.int32), t0)
+        else:
+            s = grid_start(spec, ts)
         n = state.n_slices
         open_start = jnp.where(
             n > 0, state.starts[jnp.maximum(n - 1, 0)], jnp.int64(I64_MIN))
+        if ts32:
+            open_start = _rebase32(t0, open_start)
 
         prev = jnp.concatenate([open_start[None], s[:-1]])
         newflag = (s > prev) & valid
         k = jnp.cumsum(newflag.astype(jnp.int32))          # run id per lane
         k_last = k[-1]
-        row_n = jnp.sum(valid.astype(jnp.int32))           # valid prefix len
+        # valid prefix len (the int64 form sums in int64, as jnp.sum
+        # promotes int32)
+        row_n = (jnp.sum(valid, dtype=jnp.int32) if ts32
+                 else jnp.sum(valid.astype(jnp.int32)))
 
         r_idx = jnp.arange(R, dtype=jnp.int32)
         first = jnp.searchsorted(k, r_idx, side="left")
@@ -652,6 +727,8 @@ def build_ingest_dense(spec: EngineSpec, capacity: int, runs: int,
 
         t_last_r = ts[jnp.clip(last, 0, B - 1)]
         start_r = s[jnp.clip(first, 0, B - 1)]
+        if ts32:
+            start_r = start_r.astype(jnp.int64) + t0
         ends_r = next_edge(spec, start_r)
 
         rows = jnp.clip((n - 1) + r_idx, 0, C - 1)
@@ -701,15 +778,20 @@ def build_ingest_dense(spec: EngineSpec, capacity: int, runs: int,
                 part = _combine_scatter(part, rows, upd, agg.kind)
             partials.append(part)
 
+        # ts32: ts ascends over the valid prefix, whose last lane holds
+        # the batch's max and whose length is its count
         return state._replace(
             starts=starts, ends=ends, counts=counts, t_last=t_last,
             partials=tuple(partials),
             n_slices=(n + k_last).astype(jnp.int32),
             max_event_time=jnp.maximum(
                 state.max_event_time,
-                jnp.max(jnp.where(valid, ts, I64_MIN))),
+                jnp.where(row_n > 0, ts[jnp.maximum(row_n - 1, 0)],
+                          jnp.int64(I64_MIN)) if ts32
+                else jnp.max(jnp.where(valid, ts, I64_MIN))),
             current_count=state.current_count
-            + jnp.sum(valid.astype(jnp.int64)),
+            + (row_n.astype(jnp.int64) if ts32
+               else jnp.sum(valid.astype(jnp.int64))),
             overflow=(state.overflow | (((n - 1) + k_last) >= C)
                       | (k_last > R - 1)),
         )

@@ -238,9 +238,11 @@ def _dm_ingest_kernel():
 
 def _dense_kernel(spec, capacity: int, runs: int,
                   pallas_fold: bool = False, pallas_packed: bool = False):
-    """Jitted scatter-free in-order ingest (build_ingest_dense), cached.
-    The Pallas flags are part of the cache key — a flags-off operator
-    can never be handed a Pallas-bearing executable."""
+    """Jitted scatter-free in-order ingest (build_ingest_dense), cached,
+    in its int32 offset form: the caller gives it only batches that pass
+    ``core.ts32_fits``. The Pallas flags are part of the cache key — a
+    flags-off operator can never be handed a Pallas-bearing
+    executable."""
     import jax
     from . import core as ec
 
@@ -251,7 +253,7 @@ def _dense_kernel(spec, capacity: int, runs: int,
     if hit is None:
         hit = jax.jit(ec.build_ingest_dense(
             spec, capacity, runs, pallas_fold=pallas_fold,
-            pallas_packed=pallas_packed), donate_argnums=0)
+            pallas_packed=pallas_packed, ts32=True), donate_argnums=0)
         _KERNEL_CACHE[key] = hit
     return hit
 
@@ -1362,10 +1364,17 @@ class TpuWindowOperator(WindowOperator):
         """``(kernel, runs)`` for an in-order batch spanning
         ``[ts_lo, ts_hi]``: the scatter-free dense kernel at the smallest
         rung of the run ladder that provably bounds the batch's slice
-        runs, or the general in-order kernel and 0 above the last rung."""
+        runs, or the general in-order kernel and 0 above the last rung
+        or where the span fails the dense kernel's int32 offset test."""
+        from . import core as ec
+
         pf = bool(getattr(self.config, "pallas_slice_merge", False))
         need = (ts_hi - ts_lo) // self._min_grid + 3
         runs = next((r for r in self._dense_rungs if need <= r), 0)
+        if runs and not ec.ts32_fits(self._grid_spec, ts_lo, ts_hi):
+            if self.obs is not None:
+                self.obs.counter(_obs.INGEST_DENSE_TS32_FALLBACKS).inc()
+            runs = 0
         if runs:
             if self._ingest_dense is None:
                 self._build_dense(pf)
@@ -1377,6 +1386,7 @@ class TpuWindowOperator(WindowOperator):
                 _pl.record_dispatch(self.obs)
             if self.obs is not None:
                 self.obs.counter(_obs.INGEST_DENSE_BATCHES).inc()
+                self.obs.counter(_obs.INGEST_DENSE_TS32_BATCHES).inc()
             return self._ingest_dense[runs], runs
         if pf:
             # a flagged batch over the runs bound (or dense ingest
@@ -1678,7 +1688,7 @@ class TpuWindowOperator(WindowOperator):
             kind = "dense" if runs else "inorder"
         with _obs.program_span(self.obs, "ingest.dispatch", lanes=B,
                                n_valid=n, late=has_late, kernel=kind,
-                               runs=runs):
+                               runs=runs, ts32=bool(runs)):
             self._state = kern(self._state, ts, vals, valid)
         if self._has_count:
             # device batches with count windows are in-order by contract
@@ -1715,7 +1725,7 @@ class TpuWindowOperator(WindowOperator):
         self._host_count += n
         with _obs.program_span(self.obs, "ingest.dispatch",
                                lanes=ts.shape[0], n_valid=n, late=True,
-                               kernel="general", runs=0):
+                               kernel="general", runs=0, ts32=False):
             self._state = self._ingest(self._state, ts, vals, valid)
 
     # -- watermark ---------------------------------------------------------

@@ -37,6 +37,9 @@ Stable metric-name contract (documented in README.md / docs/API.md):
 ``ingest_tuples``         counter: tuples accepted (operator or connector)
 ``ingest_batch_size``     histogram: tuples per host batch
 ``ingest_dense_batches``  counter: in-order batches given the dense kernel
+``ingest_dense_ts32_batches``    counter: dense batches in int32 offsets
+``ingest_dense_ts32_fallbacks``  counter: dense-rung batches whose span
+                                 fails the int32 offset test (general kernel)
 ``late_tuples``           counter: tuples arriving below the stream's max ts
 ``dropped_tuples``        counter: tuples older than watermark - lateness
 ``watermarks``            counter: watermark advances
@@ -199,6 +202,8 @@ from .spans import Span, SpanRecorder, program_span
 INGEST_TUPLES = "ingest_tuples"
 INGEST_BATCH_SIZE = "ingest_batch_size"
 INGEST_DENSE_BATCHES = "ingest_dense_batches"
+INGEST_DENSE_TS32_BATCHES = "ingest_dense_ts32_batches"
+INGEST_DENSE_TS32_FALLBACKS = "ingest_dense_ts32_fallbacks"
 LATE_TUPLES = "late_tuples"
 DROPPED_TUPLES = "dropped_tuples"
 WATERMARKS = "watermarks"
@@ -416,6 +421,11 @@ METRIC_HELP = {
     INGEST_BATCH_SIZE: "tuples per host batch",
     INGEST_DENSE_BATCHES: "in-order batches ingested by the scatter-free "
                           "dense kernel",
+    INGEST_DENSE_TS32_BATCHES: "dense-kernel batches whose timestamp "
+                               "arithmetic ran in int32 offsets",
+    INGEST_DENSE_TS32_FALLBACKS: "in-order batches within a dense rung "
+                                 "whose span fails the int32 offset test "
+                                 "(general kernel)",
     LATE_TUPLES: "tuples arriving below the stream's max event time",
     DROPPED_TUPLES: "tuples older than watermark - allowed lateness",
     WATERMARKS: "watermark advances",
@@ -869,6 +879,7 @@ __all__ = [
     "DEVICE_TRIGGERS_FIRED", "DEVICE_WINDOWS_NONEMPTY",
     "DEVICE_SLICES_TOUCHED", "DEVICE_SILENT_INTERVALS",
     "INGEST_TUPLES", "INGEST_BATCH_SIZE", "INGEST_DENSE_BATCHES",
+    "INGEST_DENSE_TS32_BATCHES", "INGEST_DENSE_TS32_FALLBACKS",
     "LATE_TUPLES", "DROPPED_TUPLES",
     "WATERMARKS", "WATERMARK_LAG_MS", "WATERMARK_DISPATCH_MS",
     "INTERVAL_STEP_MS", "SYNC_MS", "SLICE_OCCUPANCY", "SLICE_HEADROOM",

@@ -11,16 +11,24 @@ provably fits. Covered here:
   bit-identical, min/max identical, sums within float32 rounding, for a
   full batch, a batch with a valid prefix, and a device mask as the
   shaper's sort-and-split hands its in-order block over;
+* the kernel's int32 offset form (``ts32=True``, the one the operator
+  runs) against its int64 form: the state after every batch equal field
+  by field, over the grid specs the operator builds and timestamps near
+  0, past 2**31 and 2**40, and after a gap that leaves the open slice
+  far before the batch;
 * the operator's choice: a ``Sliding(600, 1)`` stream whose batches span
   about 150 runs takes the dense kernel (``ingest_dense_batches``, the
   ``ingest.dispatch`` span's ``kernel``/``runs`` args), a batch over the
-  top rung falls back to the general kernel with the same windows;
+  top rung falls back to the general kernel with the same windows, and
+  so does a batch whose span fails the int32 offset test (counted, and
+  named by the span's ``ts32`` arg);
 * every ``dot_general`` of the kernel runs at ``HIGHEST`` precision (on
   the TPU a default-precision float32 dot is one bfloat16 pass);
 * once the dense path is built, the first dispatch at each rung compiles
   nothing.
 """
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -30,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from scotty_tpu import (MaxAggregation, MinAggregation, SlidingWindow,
-                        SumAggregation, WindowMeasure)
+                        SumAggregation, TumblingWindow, WindowMeasure)
 from scotty_tpu import obs as _obs
 from scotty_tpu.engine import EngineConfig, TpuWindowOperator
 from scotty_tpu.engine import core as ec
@@ -113,6 +121,88 @@ def test_dense_kernel_matches_general_inorder(runs, aggs, mode):
             np.testing.assert_array_equal(g, w)
 
 
+GAP = 1 << 33
+#: grid specs of the int32 offset cases, by name -> (periods,
+#: offset_periods, bands as (start, size) offsets from the stream's base)
+NARROW_SPECS = {
+    "ms": ((1,), (), ()),
+    "several": ((3, 7, 10), (), ()),
+    # 1,000 tumbling sizes collapse to their gcd (here 4)
+    "gcd1000": (ec.collapse_periods(
+        4 * np.random.default_rng(0).integers(250, 5000, 1000)), (), ()),
+    # Sliding(25, 10): window ends off the slide grid
+    "offset": ((10,), ((10, 5),), ()),
+    "bands": ((), (), ((-5, 70), (200, 300), (GAP - 2, 400))),
+}
+NARROW_BASES = {"near0": 0, "2e31": (1 << 31) + 12345,
+                "2e40": (1 << 40) + 7, "gap": (1 << 31) + 99}
+#: (runs, aggs, lanes, batch span in grid steps), one per case in turn
+NARROW_RUNGS = ((16, "sum_min_max", 160, 10),
+                (256, "sum", 2048, 150),
+                (4096, "sum_min_max", 4096, 2500),
+                (256, "sum_min_max", 2048, 150))
+NARROW_CASES = [(k, b) + NARROW_RUNGS[i % len(NARROW_RUNGS)]
+                for i, (k, b) in enumerate(
+                    (k, b) for k in NARROW_SPECS for b in NARROW_BASES)]
+
+
+@functools.lru_cache(maxsize=None)
+def _dense_pair(kind: str, base: int, aggs: str, runs: int):
+    periods, offs, bands = NARROW_SPECS[kind]
+    spec = ec.EngineSpec(
+        periods=tuple(int(p) for p in periods), count_periods=(),
+        offset_periods=offs,
+        bands=tuple((max(base + bs, 0), sz) for bs, sz in bands),
+        aggs=tuple(a().device_spec() for a in AGGS[aggs]))
+    return spec, tuple(jax.jit(ec.build_ingest_dense(spec, C, runs, ts32=f))
+                       for f in (False, True))
+
+
+@pytest.mark.parametrize(
+    "kind,base,runs,aggs,B,steps", NARROW_CASES,
+    ids=[f"{k}-{b}-r{r}-{a}" for k, b, r, a, *_ in NARROW_CASES])
+def test_narrow_form_is_bit_identical(kind, base, runs, aggs, B, steps):
+    base = NARROW_BASES[base]
+    spec, (wide, narrow) = _dense_pair(kind, base, aggs, runs)
+    span = steps * eop.min_grid_period(spec)
+    rng = np.random.default_rng(runs + base % 997)
+    want = got = ec.init_state(spec, C, A)
+    t0 = base
+    for i in range(6):
+        n = B if i < 5 else B // 3                  # the last one partial
+        ts = np.sort(rng.integers(t0, t0 + span, B)).astype(np.int64)
+        ts[n:] = ts[n - 1]
+        vals = (rng.random(B) * 10000).astype(np.float32)
+        valid = np.arange(B) < n
+        assert ec.ts32_fits(spec, int(ts[0]), int(ts[n - 1]))
+        want = wide(want, ts, vals, valid)
+        got = narrow(got, ts, vals, valid)
+        for f, g, w in zip(want._fields, jax.device_get(got),
+                           jax.device_get(want)):
+            for gl, wl in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+                np.testing.assert_array_equal(gl, wl, err_msg=f"{f} @{i}")
+        # the gap case jumps 2**33 ms after its first batch: the open
+        # slice then starts far before the next batch's first timestamp
+        t0 += span + (GAP if NARROW_BASES["gap"] == base and i == 0 else 0)
+    got = jax.device_get(got)
+    assert got.n_slices >= 2 and not got.overflow
+
+
+def test_ts32_fits_bounds_the_offsets():
+    spec = _spec("sum")
+    assert ec.ts32_fits(spec, 0, (1 << 31) - 2)
+    assert not ec.ts32_fits(spec, 0, (1 << 31) - 1)
+    assert not ec.ts32_fits(spec, -1, 10)
+    wide = dataclasses.replace(spec, periods=(1 << 30,))
+    assert ec.ts32_fits(wide, 1 << 40, (1 << 40) + (1 << 30) - 1)
+    assert not ec.ts32_fits(wide, 1 << 40, (1 << 40) + (1 << 30))
+    # bands alone: the first lane's distance to its band edge counts
+    bands = dataclasses.replace(spec, periods=(), bands=((100, 50),))
+    lo = 150 + (1 << 31) - 1000
+    assert ec.ts32_fits(bands, lo, lo + 999)
+    assert not ec.ts32_fits(bands, lo, lo + 1000)
+
+
 def _operator(dense_runs: int = 16, obs=None, batch: int = 2048,
               pallas: bool = False):
     op = TpuWindowOperator(
@@ -185,12 +275,14 @@ def test_dispatch_span_names_kernel_and_runs(monkeypatch):
 
     def record(obs, name, **args):
         if name == "ingest.dispatch":
-            seen.append((args["kernel"], args["runs"], args["late"]))
+            seen.append((args["kernel"], args["runs"], args["late"],
+                         args["ts32"]))
         return real(obs, name, **args)
 
     monkeypatch.setattr(_obs, "program_span", record)
     B = 2048
-    op = _operator(batch=B)
+    o = _obs.Observability()
+    op = _operator(batch=B, obs=o)
     batches = list(_stream([148, 148, 5000], B=B))
     for vals, ts in batches:
         op.ingest_device_batch(jnp.asarray(vals), jnp.asarray(ts),
@@ -201,8 +293,31 @@ def test_dispatch_span_names_kernel_and_runs(monkeypatch):
                           jnp.ones(16, bool), 16, int(late_ts[0]),
                           int(late_ts[-1]))
     op.check_overflow()
-    assert seen == [("dense", 256, False), ("dense", 256, False),
-                    ("inorder", 0, False), ("general", 0, True)]
+    assert seen == [("dense", 256, False, True), ("dense", 256, False, True),
+                    ("inorder", 0, False, False), ("general", 0, True, False)]
+    assert o.counter(_obs.INGEST_DENSE_TS32_BATCHES).value == 2
+    assert o.counter(_obs.INGEST_DENSE_TS32_FALLBACKS).value == 0
+
+    # on a grid of 2**30 ms, a batch spanning 2**31 ms fits the first rung
+    # (5 runs) but not the int32 offsets: the general kernel, counted
+    seen.clear()
+    o = _obs.Observability()
+    wide = TpuWindowOperator(
+        config=EngineConfig(capacity=1 << 10, annex_capacity=64,
+                            batch_size=B, min_trigger_pad=32), obs=o)
+    wide.add_window_assigner(TumblingWindow(Time, 1 << 30))
+    wide.add_aggregation(SumAggregation())
+    rng = np.random.default_rng(5)
+    for lo, hi in ((0, 1 << 29), ((1 << 29) + 1, (1 << 29) + (1 << 31))):
+        ts = np.sort(rng.integers(lo, hi + 1, B)).astype(np.int64)
+        ts[0], ts[-1] = lo, hi
+        wide.ingest_device_batch(jnp.ones(B, jnp.float32), jnp.asarray(ts),
+                                 lo, hi)
+    wide.check_overflow()
+    assert seen == [("dense", 16, False, True), ("inorder", 0, False, False)]
+    assert o.counter(_obs.INGEST_DENSE_TS32_BATCHES).value == 1
+    assert o.counter(_obs.INGEST_DENSE_TS32_FALLBACKS).value == 1
+    assert o.counter(_obs.INGEST_DENSE_BATCHES).value == 1
 
 
 @pytest.mark.parametrize("first,pallas,want", [

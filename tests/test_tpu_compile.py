@@ -5,7 +5,8 @@ shapes off the (8, 128) tiling, primitives Mosaic cannot lower, programs
 that do not fit the device). Here each program is lowered at the width a
 deployment runs and compiled for a ``v5e:2x2`` topology: the four Pallas
 kernels with ``interpret=False``, the dense ingest kernel at each rung
-of the operator's run ladder, the ``entry()`` ingest step, the
+of the operator's run ladder (in its int64 and its int32 offset form),
+the ``entry()`` ingest step, the
 headline ``AlignedStreamPipeline`` step at capacity ``1 << 17``, and the
 mesh keyed step over the four described chips. Nothing runs; a pass says
 the chip's compiler accepts the program, not that it is right or fast.
@@ -163,6 +164,38 @@ def test_dense_ingest_rung_compiles(one_chip, runs):
              _sds((B,), jnp.int64, one_chip),
              _sds((B,), jnp.float32, one_chip),
              _sds((B,), jnp.bool_, one_chip))
+
+
+@pytest.mark.parametrize("runs", [16, 256, 4096])
+def test_dense_ingest_narrow_rung_compiles(one_chip, runs):
+    """The same rungs in the int32 offset form the operator builds: the
+    only int64 ops over the batch's lanes read the timestamps (the low
+    words, the first lane, the last valid lane, each run's last lane)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from scotty_tpu import MaxAggregation, MinAggregation, SumAggregation
+    from scotty_tpu.engine import core as ec
+
+    spec = ec.EngineSpec(
+        periods=(1,), bands=(), count_periods=(),
+        aggs=tuple(a().device_spec() for a in (
+            SumAggregation, MinAggregation, MaxAggregation)))
+    C, A, B = 1 << 17, 1 << 12, 1 << 18
+    state = _shapes(jax.eval_shape(lambda: ec.init_state(spec, C, A)),
+                    one_chip)
+    args = (state, _sds((B,), jnp.int64, one_chip),
+            _sds((B,), jnp.float32, one_chip),
+            _sds((B,), jnp.bool_, one_chip))
+    fn = ec.build_ingest_dense(spec, C, runs, ts32=True)
+    text = jax.jit(fn).lower(*args).as_text()
+    wide = {m.group(1) for ln in text.splitlines()
+            if f"tensor<{B}xi64>" in ln and "func.func" not in ln
+            for m in [re.search(r"stablehlo\.(\w+)", ln)]}
+    assert wide == {"slice", "convert", "gather", "dynamic_slice"}, wide
+    _compile(fn, *args)
 
 
 # -- whole XLA steps ---------------------------------------------------------
